@@ -117,6 +117,9 @@ def main(argv=None) -> int:
                   "(benchmarks/run.py does)", file=sys.stderr)
             return 2
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()   # after _force_devices: it imports jax
     results: dict[str, list[dict]] = {}
     failures = 0
     if args.csv:

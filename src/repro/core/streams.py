@@ -1058,20 +1058,11 @@ def gather_streams(stream, axis_name: str, *, tiled: bool = False,
 
 # ----------------------------------------- client-parallel (sharded) round
 def shard_map_clients(f, mesh, in_specs, out_specs):
-    """Full-manual shard_map across jax versions (1-D ``clients`` mesh).
-
-    jax >= 0.6 exposes jax.shard_map(check_vma=); earlier versions have
-    jax.experimental.shard_map.shard_map(check_rep=). The partial-manual
-    variant (manual over one axis of a larger mesh) lives in launch/train.py;
-    this one is full manual, which every jaxlib >= 0.4.36 partitions fine.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """Full-manual shard_map over the 1-D ``clients`` mesh. The
+    partial-manual variant (manual over one axis of a larger mesh) lives in
+    launch/train.py."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def shard_client_tree(tree, mesh):

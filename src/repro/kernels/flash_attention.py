@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -114,20 +115,9 @@ def flash_attention(
                                lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            _scratch((block_q, 1), jnp.float32),
-            _scratch((block_q, 1), jnp.float32),
-            _scratch((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-
-
-def _scratch(shape, dtype):
-    from jax.experimental import pallas as pl  # local: keep module import light
-
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover — interpret-only environments
-        return pl.MemorySpace.ANY(shape, dtype)  # type: ignore

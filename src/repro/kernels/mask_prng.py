@@ -8,6 +8,8 @@ fraction (sigma-p)/q = k/x), and add it to the gradient tile in one pass.
 
 Both endpoints of a pair run the same kernel with the same seed and opposite
 ``sign``, so the aggregated masks cancel exactly. Matches ref.mask_prng_ref.
+That dense kernel has no caller and Mosaic refuses its uint32 -> f32 cast;
+the sparse ``pair_mask_streams`` below is the one secure aggregation runs.
 """
 from __future__ import annotations
 
@@ -68,30 +70,45 @@ def mask_prng_apply(g: jax.Array, seed: int, *, p: float = -1.0, q: float = 2.0,
     return unpad(out), unpad(mask)
 
 
+def _urem(x: jax.Array, m: int) -> jax.Array:
+    """``x % m`` for uint32 ``x`` and a static ``0 < m < 2**30``, with
+    signed int32 operations only: ``x = 2*(x >> 1) + (x & 1)`` with
+    ``x >> 1 < 2**31``, so
+    ``t = 2*((x >> 1) % m) + (x & 1) < 2*m`` and one conditional subtract
+    finishes. Returns int32 in ``[0, m)``."""
+    half = jax.lax.bitcast_convert_type(x >> 1, jnp.int32)
+    low = jax.lax.bitcast_convert_type(x & jnp.uint32(1), jnp.int32)
+    t = 2 * jax.lax.rem(half, jnp.int32(m)) + low
+    return jnp.where(t >= m, t - m, t)
+
+
 def _pair_stream_kernel(s_ref, sg_ref, i_ref, v_ref, *, L: int, m: int,
                         p: float, q: float, rows: int):
     """One grid step = one pair: counter-based (idx, val) slots for that pair.
 
-    The per-pair seed arrives as a (1, 1)-blocked 2-D operand — rank >= 2 is
-    what Mosaic accepts for VMEM inputs (rank-1 blocks only lower in
-    interpret mode); a scalar-prefetch SMEM ride would also work but the
-    plain 2-D BlockSpec keeps the interpret and TPU paths identical.
-    Counters past ``L`` are padding lanes; they are zeroed and sliced off by
-    the wrapper.
+    The per-pair seed and sign arrive as ``(1, 1)`` tiles of ``(N, 1, 1)``
+    operands (block ``(None, 1, 1)``): a block's last two dims must be
+    multiples of (8, 128) or the full dims, which a ``(1, 1)`` block of an
+    ``(N, 1)`` array is not. Both stay ``(1, 1)`` vectors and broadcast
+    against the counter tile. Counters past ``L`` are padding lanes; they
+    are zeroed and sliced off by the wrapper. The uint32 -> f32 and remainder
+    steps go through int32 (exact: draws are < 2**24, and see ``_urem``).
     """
-    seed = s_ref[0, 0]
-    sign = sg_ref[0, 0]
-    c = (jax.lax.broadcasted_iota(jnp.uint32, (rows, LANE), 0) * LANE
-         + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANE), 1))
+    seed = s_ref[...]                                     # uint32[1, 1]
+    sign = sg_ref[...]                                    # f32[1, 1]
+    ci = (jax.lax.broadcasted_iota(jnp.int32, (rows, LANE), 0) * LANE
+          + jax.lax.broadcasted_iota(jnp.int32, (rows, LANE), 1))
+    c = jax.lax.bitcast_convert_type(ci, jnp.uint32)
     base_i = _mix32(seed ^ jnp.uint32(IDX_SALT))
     base_v = _mix32(seed ^ jnp.uint32(VAL_SALT))
-    idx = (_mix32(base_i + c) % jnp.uint32(m)).astype(jnp.int32)
+    idx = _urem(_mix32(base_i + c), m)
     # top 24 bits: the f32-exact uniform grid (see ref.pair_mask_stream_ref)
-    u = (_mix32(base_v + c) >> 8).astype(jnp.float32) / jnp.float32(2**24)
+    draw = jax.lax.bitcast_convert_type(_mix32(base_v + c) >> 8, jnp.int32)
+    u = draw.astype(jnp.float32) / jnp.float32(2**24)
     val = sign * (p + q * u)
-    valid = c < jnp.uint32(L)
-    i_ref[...] = jnp.where(valid, idx, 0)[None]
-    v_ref[...] = jnp.where(valid, val, 0.0)[None]
+    valid = ci < L
+    i_ref[...] = jnp.where(valid, idx, 0)
+    v_ref[...] = jnp.where(valid, val, 0.0)
 
 
 def pair_mask_streams(seeds: jax.Array, signs: jax.Array, *, nb: int,
@@ -107,6 +124,8 @@ def pair_mask_streams(seeds: jax.Array, signs: jax.Array, *, nb: int,
     a murmur-avalanched counter stream, so masks are regenerated on the fly
     (zero HBM for the mask matrix) exactly as the dense kernel does.
     """
+    if not 0 < m < 2**30:
+        raise ValueError(f"block length m={m} outside (0, 2**30)")
     n_pairs = seeds.shape[0]
     L = nb * k_mask
     rows = max(1, -(-L // LANE))
@@ -116,20 +135,20 @@ def pair_mask_streams(seeds: jax.Array, signs: jax.Array, *, nb: int,
         kernel,
         grid=(n_pairs,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, rows, LANE), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, rows, LANE), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, rows, LANE), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, rows, LANE), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pairs, rows, LANE), jnp.int32),
             jax.ShapeDtypeStruct((n_pairs, rows, LANE), jnp.float32),
         ],
         interpret=interpret,
-    )(seeds.astype(jnp.uint32).reshape(n_pairs, 1),
-      signs.astype(jnp.float32).reshape(n_pairs, 1))
+    )(seeds.astype(jnp.uint32).reshape(n_pairs, 1, 1),
+      signs.astype(jnp.float32).reshape(n_pairs, 1, 1))
     idx = idx.reshape(n_pairs, rows * LANE)[:, :L].reshape(n_pairs, nb, k_mask)
     vals = vals.reshape(n_pairs, rows * LANE)[:, :L].reshape(
         n_pairs, nb, k_mask)

@@ -1,8 +1,14 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel body
-runs as traced jnp ops, which is how correctness is validated against ref.py.
-On TPU they compile to Mosaic with the BlockSpec tilings declared in each file.
+Off-TPU the kernels execute in interpret mode — the kernel body runs as
+traced jnp ops, which is how correctness is validated against ref.py. On TPU
+they compile to Mosaic. The main-path kernels (``stream_scatter_add``,
+``pair_mask_streams``, ``bitpack_rows``/``bitunpack_rows``) are compiled for
+a described v5e in tests/test_tpu_compile.py and checked on the chip by
+chip_smoke.py. ``flash_attention``, ``thgs_sparsify`` and
+``mask_prng_apply`` have no caller on the main path and have not been
+compiled for a chip (``mask_prng_apply``'s uint32 -> f32 cast is one Mosaic
+refuses).
 """
 from __future__ import annotations
 

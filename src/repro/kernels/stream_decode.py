@@ -50,7 +50,10 @@ def _kernel(idx_ref, val_ref, o_ref, *, tile_rows: int):
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (kc, LANE), 1)
     lanehot = (lane_iota == lane.reshape(kc, 1)).astype(jnp.float32)
     weighted = val.reshape(kc, 1) * lanehot                       # [KC, LANE]
+    # HIGHEST: a bf16-pass MXU product would round the stream values, and
+    # pair masks cancel only if grid values survive the decode exactly
     o_ref[...] += jax.lax.dot(rowhot, weighted,
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
 
@@ -72,19 +75,21 @@ def stream_scatter_add(
                   constant_values=-1)
     val = jnp.pad(values.reshape(-1).astype(jnp.float32), (0, pad_n))
     n_chunks = idx.shape[0] // chunk
-    idx2 = idx.reshape(n_chunks, chunk)
-    val2 = val.reshape(n_chunks, chunk)
+    # (n_chunks, 1, chunk), the leading axis squeezed from the block: the
+    # block's last two dims then equal the array's, as Mosaic requires
+    idx3 = idx.reshape(n_chunks, 1, chunk)
+    val3 = val.reshape(n_chunks, 1, chunk)
 
     dense = pl.pallas_call(
         functools.partial(_kernel, tile_rows=tile_rows),
         grid=(n_tiles, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, chunk), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (j, 0)),
+            pl.BlockSpec((None, 1, chunk), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((None, 1, chunk), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_rows, LANE), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * tile_rows, LANE),
                                        jnp.float32),
         interpret=interpret,
-    )(idx2, val2)
+    )(idx3, val3)
     return dense.reshape(-1)[:size]

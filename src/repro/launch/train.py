@@ -39,21 +39,10 @@ PyTree = Any
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs, manual_axes):
-    """Partial-manual shard_map across jax versions.
-
-    jax >= 0.6 exposes jax.shard_map(axis_names=manual set, check_vma=);
-    earlier versions have jax.experimental.shard_map(auto=complement set,
-    check_rep=). Both mean the same: manual only over ``manual_axes``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names=set(manual_axes))
-    from jax.experimental.shard_map import shard_map
-
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False, auto=auto)
+    """Partial-manual shard_map: manual only over ``manual_axes``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=set(manual_axes))
 
 
 def loss_fn(params: PyTree, cfg: ArchConfig, batch: dict) -> jax.Array:

@@ -52,8 +52,10 @@ def main(argv=None) -> int:
     import jax
 
     from repro import serving
+    from repro.compile_cache import enable_compile_cache
     from repro.sim import Simulation, presets, publish_params_hook
 
+    enable_compile_cache()
     cfg = presets.get(args.preset)
     over: dict = {"ckpt_dir": None, "ckpt_every": 0, "out_json": None}
     if cfg.mode != "sync":

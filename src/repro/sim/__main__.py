@@ -16,6 +16,7 @@ import json
 import os
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.codecs import CODECS
 from repro.sim import presets
 from repro.sim.engine import AsyncSimulation, Simulation
@@ -189,6 +190,7 @@ def main(argv=None) -> int:
                   f"{', '.join(f'{z:g}' for z in sigmas)}")
         return 0 if args.list else 2
 
+    enable_compile_cache()
     if args.preset in presets.SWEEPS:
         return _run_sweep(args)
     if args.preset in presets.DP_SWEEPS:

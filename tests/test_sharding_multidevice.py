@@ -2,23 +2,15 @@
 (the main test process must keep seeing exactly 1 device).
 
 The client-parallel round (full-manual shard_map over a 1-D clients mesh,
-DESIGN.md §11) has no such version floor and is covered on every runtime by
-tests/test_client_sharded_round.py; only the partial-manual FL mesh step
-below needs the jaxlib >= 0.5 SPMD partitioner.
+DESIGN.md §11) is covered by tests/test_client_sharded_round.py; this file
+drives the partial-manual FL mesh step of launch/train.py.
 """
 import json
 import os
 import subprocess
 import sys
 
-import re
-
-import jaxlib
 import pytest
-
-# tolerant of pre-release suffixes ('0.5.0rc1'); unparseable -> (0, 0) = skip
-_m = re.match(r"(\d+)\.(\d+)", jaxlib.__version__)
-_JAXLIB_VERSION = (int(_m.group(1)), int(_m.group(2))) if _m else (0, 0)
 
 SNIPPET = r"""
 import os
@@ -74,14 +66,6 @@ print(json.dumps({"losses": losses, "dense_loss": float(dloss),
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    _JAXLIB_VERSION < (0, 5),
-    reason="partial-manual shard_map with sharding constraints / collectives "
-           "inside the manual region aborts jaxlib<0.5's SPMD partitioner "
-           "(XLA CHECK 'IsManualSubgroup', uncatchable process abort). Keyed "
-           "on the actual jaxlib floor — the previous hasattr(jax, "
-           "'shard_map') marker only appears in jax>=0.6 and skipped "
-           "working 0.5.x runtimes")
 def test_fl_step_on_multipod_mesh():
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
