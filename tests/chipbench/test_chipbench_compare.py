@@ -1,0 +1,82 @@
+"""The comparison's arithmetic on made-up outputs, and the window's
+end-to-end arithmetic."""
+import math
+
+import numpy as np
+import pytest
+
+import chipbench_testing  # noqa: F401  (puts the checkout on sys.path)
+
+from chipbench import compare, harness
+from chipbench.reference import Outputs
+
+
+def _outputs(scale=1.0, bias_update=1e-9, loss=(2.0, 1.0, 0.5)):
+    rng = np.random.default_rng(0)
+    p0 = {"a": rng.normal(size=100), "b": rng.normal(size=10),
+          "c": np.zeros(5)}
+    upd = {"a": 0.1 * rng.normal(size=100), "b": 0.1 * rng.normal(size=10),
+           "c": np.full(5, bias_update)}
+    p1 = {k: p0[k] + scale * upd[k] for k in p0}
+    res = {k: np.stack([upd[k], -upd[k]]) for k in p0}
+    return Outputs(losses=list(loss), params0=p0, params1=p1, params_n=p1,
+                   residuals=res)
+
+
+def test_identical_sides_read_zero():
+    nums = compare.numbers(_outputs(), _outputs())
+    assert set(nums) == set(compare.NUMBERS)
+    assert all(v == 0.0 for v in nums.values())
+
+
+def test_unchanged_state_reads_one():
+    ref = _outputs()
+    frozen = _outputs(scale=0.0)
+    nums = compare.numbers(frozen, ref)
+    assert nums["update1"] == pytest.approx(1.0)
+    assert nums["change_n"] == pytest.approx(1.0)
+
+
+def test_negligible_leaf_is_left_out():
+    """A leaf whose reference update is under 1e-3 of the median leaf's
+    does not count, however far the program's is from it."""
+    ref = _outputs(bias_update=1e-9)
+    prog = _outputs(bias_update=3e-9)
+    assert compare.numbers(prog, ref)["update1"] == 0.0
+
+
+def test_loss_gaps_and_nan():
+    ref = _outputs(loss=(2.0, 1.0, 0.5))
+    prog = _outputs(loss=(2.0, 1.1, float("nan")))
+    nums = compare.numbers(prog, ref)
+    assert nums["loss1"] == 0.0 and math.isnan(nums["loss"])
+    assert not compare.verdict(nums, {"loss": 1.0})
+    assert compare.verdict(nums, {"loss1": 1e-9})
+
+
+def test_a_cell_must_compare_something():
+    assert not compare.verdict(dict.fromkeys(compare.NUMBERS, 0.0),
+                               {"readings": {}})
+
+
+def test_nearest_rank_percentile():
+    values = list(range(1, 101))
+    assert harness.nearest_rank(values, 0.9) == 90
+    assert harness.nearest_rank([3.0], 0.9) == 3.0
+    assert harness.nearest_rank([5, 1, 4, 2, 3], 0.9) == 5
+
+
+def test_block_dropouts_give_every_seed_the_same_mix():
+    traffic = {"dropout_blocks": [0, 1, 1, 2]}
+    cohort = [1, 3, 4, 7, 9]
+    for seed in (1, 2147483701, 2**31 + 5):
+        draw = harness.block_dropouts(traffic, seed)
+        counts = [len(draw(r, cohort, min_survivors=3)) for r in range(12)]
+        for b in range(3):
+            assert sorted(counts[4 * b:4 * b + 4]) == [0, 1, 1, 2]
+        assert all(set(draw(r, cohort)) <= set(cohort) for r in range(12))
+        assert draw(5, cohort) == draw(5, cohort)
+    assert harness.block_dropouts({"dropout_rate": 0.2}, 1) is None
+    with pytest.raises(ValueError, match="cannot drop"):
+        draw = harness.block_dropouts({"dropout_blocks": [3]}, 1)
+        draw(0, cohort, min_survivors=3)

@@ -1,0 +1,99 @@
+"""``correct`` comes out false when the timed path is broken underneath
+(a run of the tiny CPU cell with one fault planted in the program), and
+for the control: the plain reference computed in bfloat16 put in the
+program's place."""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from chipbench_testing import (DATA, any_device, jax_settings,  # noqa: F401
+                               load_script, tiny_argv)
+
+from chipbench import compare, harness, spec
+
+run_script = load_script("run")
+
+
+def _unchanged(monkeypatch):
+    """A round that returns the params it was given."""
+    from repro.sim import engine
+
+    real = engine.run_round
+
+    def frozen(state, *a, **kw):
+        params = state.params
+        out = real(state, *a, **kw)
+        out.params = params
+        return out
+
+    monkeypatch.setattr(engine, "run_round", frozen)
+
+
+def _half_batch(monkeypatch):
+    """Local SGD's loss over the first half of each batch."""
+    from repro.sim import engine
+
+    real = engine.cross_entropy_loss
+
+    def half(model):
+        full = real(model)
+
+        def loss_fn(params, batch):
+            x, y = batch
+            n = x.shape[0] // 2
+            return full(params, (x[:n], y[:n]))
+        return loss_fn
+
+    monkeypatch.setattr(engine, "cross_entropy_loss", half)
+
+
+def _lost_upload(monkeypatch):
+    """The decode leaves the first client's stream out."""
+    from repro.core import streams
+
+    real = streams._flatten_round_stream
+
+    def lossy(batch, alive, weights, extra):
+        batch = batch._replace(values=batch.values.at[0].set(0.0))
+        return real(batch, alive, weights, extra)
+
+    monkeypatch.setattr(streams, "_flatten_round_stream", lossy)
+
+
+def _no_recovery(monkeypatch):
+    """Dropped clients' masks are not cancelled (recovery streams zero)."""
+    from repro.core import streams
+
+    real = streams.dropout_cancel_streams_seeded
+
+    def silent(*a, **kw):
+        out = real(*a, **kw)
+        return out._replace(values=jnp.zeros_like(out.values))
+
+    monkeypatch.setattr(streams, "dropout_cancel_streams_seeded", silent)
+
+
+FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,
+          "lost_upload": _lost_upload, "no_recovery": _no_recovery}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_broken_round_is_not_correct(fault, monkeypatch, capsys,
+                                     jax_settings):
+    jax.clear_caches()          # the planted fault has to be traced afresh
+    FAULTS[fault](monkeypatch)
+    doc = run_script.main(tiny_argv(seed=31), bench_file=DATA /
+                          "BENCHMARK.json", root=DATA, chip_check=any_device)
+    assert doc["correct"] is False
+    assert any(c["value"] == "nan" or c["value"] > c["limit"]
+               for c in doc["checks"].values())
+
+
+def test_control_is_not_correct(jax_settings):
+    cell = spec.resolve("tiny_drop", DATA / "BENCHMARK.json", DATA)
+    harness.configure_jax(cell, harness.spec.CHECKOUT)
+    run = harness.run_program(cell, 2147483701, 0.0, 0.0)
+    sound = harness.check(cell, 2147483701, run)
+    control = harness.check(cell, 2147483701, run, dtype="bfloat16")
+    assert compare.verdict(sound, cell.limits)
+    assert not compare.verdict(control, cell.limits)
