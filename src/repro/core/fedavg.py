@@ -33,6 +33,7 @@ from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import costs, schedules
 from repro.core import streams as se
@@ -245,96 +246,96 @@ def run_round(
     local-SGD program); pad ragged local data to fixed [steps, batch] first,
     as data/federated.py::client_batches does.
     """
-    if topology not in ("flat", "tree"):
-        raise ValueError(f"unknown topology {topology!r}")
-    if topology == "tree" and thgs is None:
-        raise ValueError("topology='tree' requires THGS sparse streams; "
-                         "dense rounds have no stream decode to shard")
-    dp_active = dp is not None and dp.active
-    if dp_active:
-        dp.validate()
-        if thgs is None:
-            raise ValueError(
-                "dp requires THGS sparse streams; the DP noise rides the "
-                "unified stream's transmitted slots (thgs is None)")
-        from repro.core.dp import reject_codec_with_noise
+    with TraceAnnotation("fl.local_sgd"):
+        if topology not in ("flat", "tree"):
+            raise ValueError(f"unknown topology {topology!r}")
+        if topology == "tree" and thgs is None:
+            raise ValueError("topology='tree' requires THGS sparse streams; "
+                             "dense rounds have no stream decode to shard")
+        dp_active = dp is not None and dp.active
+        if dp_active:
+            dp.validate()
+            if thgs is None:
+                raise ValueError(
+                    "dp requires THGS sparse streams; the DP noise rides the "
+                    "unified stream's transmitted slots (thgs is None)")
+            from repro.core.dp import reject_codec_with_noise
 
-        reject_codec_with_noise(codec, dp.sigma)
-        if client_weights and any(
-                float(w) != 1.0 for w in client_weights.values()):
-            raise ValueError(
-                "dp requires uniform client weights: weights scale the "
-                "stream values before masking, so a weight != 1.0 would "
-                "scale that client's contribution past the clip bound S "
-                "the accountant calibrates noise against")
-    participants = sorted(client_batches.keys())
-    C = len(participants)
-    sharded = se.can_shard_clients(mesh, C)
-    dropped = set(dropped)
-    assert dropped <= set(participants), "dropped must be participants"
-    survivors = [c for c in participants if c not in dropped]
-    assert survivors, "a round needs at least one surviving client"
-    alive = jnp.asarray([c not in dropped for c in participants], bool)
-    w_list = [float(client_weights.get(c, 1.0)) if client_weights else 1.0
-              for c in participants]
-    w_vec = jnp.asarray(w_list, jnp.float32)
-    w_surv_total = sum(w for w, c in zip(w_list, participants)
-                       if c not in dropped)
+            reject_codec_with_noise(codec, dp.sigma)
+            if client_weights and any(
+                    float(w) != 1.0 for w in client_weights.values()):
+                raise ValueError(
+                    "dp requires uniform client weights: weights scale the "
+                    "stream values before masking, so a weight != 1.0 would "
+                    "scale that client's contribution past the clip bound S "
+                    "the accountant calibrates noise against")
+        participants = sorted(client_batches.keys())
+        C = len(participants)
+        sharded = se.can_shard_clients(mesh, C)
+        dropped = set(dropped)
+        assert dropped <= set(participants), "dropped must be participants"
+        survivors = [c for c in participants if c not in dropped]
+        assert survivors, "a round needs at least one surviving client"
+        alive = jnp.asarray([c not in dropped for c in participants], bool)
+        w_list = [float(client_weights.get(c, 1.0)) if client_weights
+                  else 1.0 for c in participants]
+        w_vec = jnp.asarray(w_list, jnp.float32)
+        w_surv_total = sum(w for w, c in zip(w_list, participants)
+                           if c not in dropped)
 
-    leaves, treedef = jax.tree_util.tree_flatten(state.params)
-    leaf_shapes = [x.shape for x in leaves]
-    leaf_dtypes = [x.dtype for x in leaves]
-    model_size = sum(x.size for x in leaves)
+        leaves, treedef = jax.tree_util.tree_flatten(state.params)
+        leaf_shapes = [x.shape for x in leaves]
+        leaf_dtypes = [x.dtype for x in leaves]
+        leaf_sizes = [x.size for x in leaves]
+        model_size = sum(leaf_sizes)
 
-    # ---- 1. all clients' local SGD, one vmapped dispatch ----
-    batches_stacked = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *[client_batches[c] for c in participants])
-    if sharded:
-        batches_stacked = se.shard_client_tree(batches_stacked, mesh)
-        deltas_stacked, losses = batched_client_update_sharded(
-            mesh,
-            state.params,
-            batches_stacked,
-            loss_fn,
-            fed.local_steps,
-            fed.local_lr,
-            fed.prox_mu if fed.algorithm == "fedprox" else 0.0,
-        )
-    else:
-        deltas_stacked, losses = batched_client_update(
-            state.params,
-            batches_stacked,
-            loss_fn,
-            fed.local_steps,
-            fed.local_lr,
-            fed.prox_mu if fed.algorithm == "fedprox" else 0.0,
-        )
-    losses_list = [float(x) for x in losses]
+        # ---- 1. all clients' local SGD, one vmapped dispatch ----
+        batches_stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[client_batches[c] for c in participants])
+        if sharded:
+            batches_stacked = se.shard_client_tree(batches_stacked, mesh)
+            deltas_stacked, losses = batched_client_update_sharded(
+                mesh,
+                state.params,
+                batches_stacked,
+                loss_fn,
+                fed.local_steps,
+                fed.local_lr,
+                fed.prox_mu if fed.algorithm == "fedprox" else 0.0,
+            )
+        else:
+            deltas_stacked, losses = batched_client_update(
+                state.params,
+                batches_stacked,
+                loss_fn,
+                fed.local_steps,
+                fed.local_lr,
+                fed.prox_mu if fed.algorithm == "fedprox" else 0.0,
+            )
+    with TraceAnnotation("fl.host_read", values=C):
+        losses_list = [float(x) for x in losses]
 
     if thgs is not None:
-        # per-(round, client) noise seeds and the round's public common-
-        # support seed, derived host-side so the stream is replayable from
-        # config + round alone (resume, sharded parity)
-        dp_sigma_c = dp.sigma_client(C) if dp_active else 0.0
-        dp_noised = dp_active and dp.noised
-        dp_seeds = (jnp.asarray(dp.client_seeds(state.round, participants))
-                    if dp_noised else None)
-        dp_sup_seed = dp.support_seed(state.round) if dp_noised else 0
-        # Eq. 2's beta from the federation-mean loss trajectory: one static
-        # per-leaf k for the whole batched round (per-client k would make the
-        # stacked stream shapes ragged — see DESIGN.md §3).
-        loss_prev = _mean_or_none([state.losses.get(c) for c in participants])
-        loss_curr = _mean_or_none(losses_list)
-        ks = schedules.leaf_ks(
-            thgs,
-            [x.size for x in leaves],
-            t=state.round,
-            total_rounds=fed.rounds,
-            loss_prev=loss_prev,
-            loss_curr=loss_curr,
-        )
-        use_masks = sa.enabled and C >= 2
-        se.reject_codec_with_masks(codec, use_masks)
+        with TraceAnnotation("fl.schedule"):
+            # Eq. 2's beta from the federation-mean loss trajectory: one
+            # static per-leaf k for the whole batched round (per-client k
+            # would make the stacked stream shapes ragged — see DESIGN.md §3).
+            loss_prev = _mean_or_none(
+                [state.losses.get(c) for c in participants])
+            loss_curr = _mean_or_none(losses_list)
+            ks = schedules.leaf_ks(
+                thgs,
+                leaf_sizes,
+                t=state.round,
+                total_rounds=fed.rounds,
+                loss_prev=loss_prev,
+                loss_curr=loss_curr,
+            )
+            use_masks = sa.enabled and C >= 2
+            se.reject_codec_with_masks(codec, use_masks)
+            k_masks = [sa.k_mask_for(size, C) if use_masks else 0
+                       for size in leaf_sizes]
         if use_masks:
             # the round protocol: DH pair secrets + Shamir shares (phases
             # 0-1); layering note — secagg sits beside core, this local
@@ -350,67 +351,82 @@ def run_round(
             proto = None
             pair_seeds = pair_signs = recovery_seeds = None
 
-        delta_leaves = jax.tree_util.tree_leaves(deltas_stacked)
-        res_per_client = [jax.tree_util.tree_leaves(state.residuals[c])
-                          for c in participants]
-        res_stacked = [jnp.stack([rl[i] for rl in res_per_client])
-                       for i in range(len(leaves))]
-        if dp_active and dp.clips:
-            # per-client global-L2 clip of the ENCODER INPUT — the error-
-            # feedback accumulator residual + delta — so the sensitivity
-            # bound S holds for the full stream the client emits (the
-            # residual carries untransmitted mass across rounds; clipping
-            # the fresh delta alone would not bound it). The clipped
-            # accumulator becomes the encode's update with a zeroed residual
-            # source; compliant clients scale by exactly 1.0 (core/dp.py).
-            from repro.core.dp import clip_client_updates
+        with TraceAnnotation("fl.restack"):
+            # per-(round, client) noise seeds and the round's public common-
+            # support seed, derived host-side so the stream is replayable
+            # from config + round alone (resume, sharded parity)
+            dp_sigma_c = dp.sigma_client(C) if dp_active else 0.0
+            dp_noised = dp_active and dp.noised
+            dp_seeds = (
+                jnp.asarray(dp.client_seeds(state.round, participants))
+                if dp_noised else None)
+            dp_sup_seed = dp.support_seed(state.round) if dp_noised else 0
 
-            acc_tree = jax.tree_util.tree_unflatten(
-                treedef,
-                [d.astype(jnp.float32) + r.astype(jnp.float32)
-                 for d, r in zip(delta_leaves, res_stacked)])
-            delta_leaves = jax.tree_util.tree_leaves(
-                clip_client_updates(acc_tree, clip=float(dp.clip)))
-            res_stacked = [jnp.zeros_like(r) for r in res_stacked]
-        if sharded:
-            res_stacked = [se.shard_client_tree(r, mesh) for r in res_stacked]
+            delta_leaves = jax.tree_util.tree_leaves(deltas_stacked)
+            res_per_client = [jax.tree_util.tree_leaves(state.residuals[c])
+                              for c in participants]
+            res_stacked = [jnp.stack([rl[i] for rl in res_per_client])
+                           for i in range(len(leaves))]
+            if dp_active and dp.clips:
+                # per-client global-L2 clip of the ENCODER INPUT — the error-
+                # feedback accumulator residual + delta — so the sensitivity
+                # bound S holds for the full stream the client emits (the
+                # residual carries untransmitted mass across rounds; clipping
+                # the fresh delta alone would not bound it). The clipped
+                # accumulator becomes the encode's update with a zeroed
+                # residual source; compliant clients scale by exactly 1.0
+                # (core/dp.py).
+                from repro.core.dp import clip_client_updates
 
-        groups = tree_groups if tree_groups > 0 else max(
-            2, int(round(C ** 0.5)))
+                acc_tree = jax.tree_util.tree_unflatten(
+                    treedef,
+                    [d.astype(jnp.float32) + r.astype(jnp.float32)
+                     for d, r in zip(delta_leaves, res_stacked)])
+                delta_leaves = jax.tree_util.tree_leaves(
+                    clip_client_updates(acc_tree, clip=float(dp.clip)))
+                res_stacked = [jnp.zeros_like(r) for r in res_stacked]
+            if sharded:
+                res_stacked = [se.shard_client_tree(r, mesh)
+                               for r in res_stacked]
+
+            groups = tree_groups if tree_groups > 0 else max(
+                2, int(round(C ** 0.5)))
 
         agg_leaves, new_res_leaves = [], []
-        ks_acct, k_masks_acct, leaf_sizes_acct = [], [], []
-        for leaf_id, (d_st, r_st, k, shape) in enumerate(
-                zip(delta_leaves, res_stacked, ks, leaf_shapes)):
-            size = leaves[leaf_id].size
-            k_mask = sa.k_mask_for(size, C) if use_masks else 0
-            if sharded:
-                # ---- 2+3. client-parallel encode + fused decode: one
-                # shard_map program per leaf (DESIGN.md §11) ----
-                dense, new_res = se.encode_decode_leaf_sharded(
-                    mesh, d_st, r_st, k=k, nb=1, m=size, size=size,
-                    selector=thgs.selector, sample_frac=thgs.sample_frac,
-                    pair_seeds=pair_seeds, pair_signs=pair_signs,
-                    recovery_seeds=recovery_seeds if dropped else None,
-                    alive=alive if dropped else None,
-                    k_mask=k_mask, mask_p=sa.p, mask_q=sa.q,
-                    leaf_id=leaf_id, weights=w_vec, codec=codec,
-                    topology=topology, tree_groups=groups,
-                    dp_sigma=dp_sigma_c, dp_seeds=dp_seeds,
-                    dp_support_seed=dp_sup_seed)
-            else:
+        for leaf_id, (d_st, r_st, k, k_mask, size, shape) in enumerate(
+                zip(delta_leaves, res_stacked, ks, k_masks, leaf_sizes,
+                    leaf_shapes)):
+            if not sharded:
                 # ---- 2. batched unified-stream encode (all clients, one
                 # jit) ----
-                streams_b, new_res = se.encode_leaf_batch(
-                    d_st, r_st, k=k, nb=1, m=size, size=size,
-                    selector=thgs.selector, sample_frac=thgs.sample_frac,
-                    pair_seeds=pair_seeds, pair_signs=pair_signs,
-                    k_mask=k_mask, mask_p=sa.p, mask_q=sa.q,
-                    leaf_id=leaf_id, weights=w_vec, codec=codec,
-                    dp_sigma=dp_sigma_c, dp_seeds=dp_seeds,
-                    dp_support_seed=dp_sup_seed)
+                with TraceAnnotation("fl.encode", leaf=leaf_id):
+                    streams_b, new_res = se.encode_leaf_batch(
+                        d_st, r_st, k=k, nb=1, m=size, size=size,
+                        selector=thgs.selector, sample_frac=thgs.sample_frac,
+                        pair_seeds=pair_seeds, pair_signs=pair_signs,
+                        k_mask=k_mask, mask_p=sa.p, mask_q=sa.q,
+                        leaf_id=leaf_id, weights=w_vec, codec=codec,
+                        dp_sigma=dp_sigma_c, dp_seeds=dp_seeds,
+                        dp_support_seed=dp_sup_seed)
+            with TraceAnnotation(
+                    "fl.encode_decode" if sharded else "fl.decode",
+                    leaf=leaf_id):
+                if sharded:
+                    # ---- 2+3. client-parallel encode + fused decode: one
+                    # shard_map program per leaf (DESIGN.md §11) ----
+                    dense, new_res = se.encode_decode_leaf_sharded(
+                        mesh, d_st, r_st, k=k, nb=1, m=size, size=size,
+                        selector=thgs.selector, sample_frac=thgs.sample_frac,
+                        pair_seeds=pair_seeds, pair_signs=pair_signs,
+                        recovery_seeds=recovery_seeds if dropped else None,
+                        alive=alive if dropped else None,
+                        k_mask=k_mask, mask_p=sa.p, mask_q=sa.q,
+                        leaf_id=leaf_id, weights=w_vec, codec=codec,
+                        topology=topology, tree_groups=groups,
+                        dp_sigma=dp_sigma_c, dp_seeds=dp_seeds,
+                        dp_support_seed=dp_sup_seed)
                 # ---- 3. fused scatter-add decode + dropout recovery ----
-                if topology == "tree":
+                elif topology == "tree":
                     dense = se.decode_leaf_tree(
                         streams_b, nb=1, m=size, size=size,
                         splits=se.tree_splits(size, groups),
@@ -427,82 +443,93 @@ def run_round(
                         pair_signs=pair_signs if dropped else None,
                         k_mask=k_mask, mask_p=sa.p, mask_q=sa.q,
                         leaf_id=leaf_id)
-            agg_leaves.append(
-                (dense / w_surv_total).reshape(shape)
-                .astype(leaf_dtypes[leaf_id]))
-            # dropped clients transmitted nothing: their full accumulator
-            # carries over as error feedback (nothing is lost, only delayed)
-            if dropped:
-                keep = alive.reshape((C,) + (1,) * len(shape))
-                new_res = jnp.where(
-                    keep, new_res,
-                    (r_st + d_st).astype(new_res.dtype))
-            new_res_leaves.append(new_res)
+                agg_leaves.append(
+                    (dense / w_surv_total).reshape(shape)
+                    .astype(leaf_dtypes[leaf_id]))
+            with TraceAnnotation("fl.residuals"):
+                # dropped clients transmitted nothing: their full accumulator
+                # carries over as error feedback (nothing is lost, only
+                # delayed)
+                if dropped:
+                    keep = alive.reshape((C,) + (1,) * len(shape))
+                    new_res = jnp.where(
+                        keep, new_res,
+                        (r_st + d_st).astype(new_res.dtype))
+                new_res_leaves.append(new_res)
+
+        with TraceAnnotation("fl.residuals"):
+            for ci, c in enumerate(participants):
+                state.residuals[c] = jax.tree_util.tree_unflatten(
+                    treedef, [nr[ci] for nr in new_res_leaves])
+
+    with TraceAnnotation("fl.server_update"):
+        if thgs is not None:
+            agg = jax.tree_util.tree_unflatten(treedef, agg_leaves)
             # wire accounting: the gated self-pair slot (zero value at a
             # duplicated index) is not transmitted — k + (C-1)*k_mask slots
             # per leaf, matching the paper's Eq. 6 payload; leaf_sizes feed
             # the quantized codecs' exact packed-word sizes (core/codecs.py)
-            ks_acct.append(min(int(k), size))
-            k_masks_acct.append(k_mask)
-            leaf_sizes_acct.append(size)
-
-        agg = jax.tree_util.tree_unflatten(treedef, agg_leaves)
-        for ci, c in enumerate(participants):
-            state.residuals[c] = jax.tree_util.tree_unflatten(
-                treedef, [nr[ci] for nr in new_res_leaves])
-        rec = costs.round_record(
-            state.round, model_size, ks_acct, k_masks_acct,
-            n_clients=len(participants), bits=bits,
-            n_survivors=len(survivors),
-            threshold=proto.t if use_masks else 0,
-            codec=codec, leaf_sizes=leaf_sizes_acct,
-            # facts-only DP fields: inactive parts stay at the 0.0 defaults
-            # so sigma=0/clip=inf records equal pre-DP records bit for bit
-            dp_clip=float(dp.clip) if dp_active and dp.clips else 0.0,
-            dp_sigma=float(dp.sigma) if dp_active else 0.0,
-            dp_delta=float(dp.delta) if dp_active and dp.noised else 0.0)
-    else:
-        if codec != "f32":
-            raise ValueError(
-                f"codec {codec!r} requires THGS sparse streams; dense rounds "
-                "have no stream wire to quantize (thgs is None)")
-        deltas = {c: jax.tree_util.tree_map(lambda x: x[ci], deltas_stacked)
-                  for ci, c in enumerate(participants)}
-        if sa.enabled:
-            from repro.core.secure_agg import dense_masked_update
-
-            # dense Bonawitz has no sparse-support reconstruction: masks are
-            # agreed among the survivors (the baseline's re-run assumption)
-            masked = []
-            for c in survivors:
-                leaves_c = jax.tree_util.tree_leaves(deltas[c])
-                masked.append([
-                    dense_masked_update(x, sa, c, survivors, state.round, i)
-                    for i, x in enumerate(leaves_c)
-                ])
-            summed = [
-                sum(m[i] for m in masked) / len(survivors)
-                for i in range(len(leaves))
-            ]
-            agg = jax.tree_util.tree_unflatten(
-                jax.tree_util.tree_structure(state.params),
-                [s.astype(d) for s, d in zip(summed, leaf_dtypes)],
-            )
+            rec = costs.round_record(
+                state.round, model_size,
+                [min(int(k), size) for k, size in zip(ks, leaf_sizes)],
+                k_masks, n_clients=len(participants), bits=bits,
+                n_survivors=len(survivors),
+                threshold=proto.t if use_masks else 0,
+                codec=codec, leaf_sizes=leaf_sizes,
+                # facts-only DP fields: inactive parts stay at the 0.0
+                # defaults so sigma=0/clip=inf records equal pre-DP records
+                # bit for bit
+                dp_clip=float(dp.clip) if dp_active and dp.clips else 0.0,
+                dp_sigma=float(dp.sigma) if dp_active else 0.0,
+                dp_delta=float(dp.delta) if dp_active and dp.noised else 0.0)
         else:
-            agg = jax.tree_util.tree_map(
-                lambda *xs: sum(xs) / len(xs), *[deltas[c] for c in survivors]
-            )
-        rec = costs.dense_round_record(
-            state.round, model_size, n_clients=len(participants), bits=bits,
-            n_survivors=len(survivors))
+            # the dense baseline's aggregation runs here: there is no stream
+            # encode or decode to span
+            if codec != "f32":
+                raise ValueError(
+                    f"codec {codec!r} requires THGS sparse streams; dense "
+                    "rounds have no stream wire to quantize (thgs is None)")
+            deltas = {c: jax.tree_util.tree_map(lambda x: x[ci],
+                                                deltas_stacked)
+                      for ci, c in enumerate(participants)}
+            if sa.enabled:
+                from repro.core.secure_agg import dense_masked_update
 
-    for ci, c in enumerate(participants):
-        state.losses[c] = losses_list[ci]
-    state.params = jax.tree_util.tree_map(
-        lambda p, d: p + fed.server_lr * d, state.params, agg
-    )
-    state.comm_log.append(rec)
-    state.round += 1
+                # dense Bonawitz has no sparse-support reconstruction: masks
+                # are agreed among the survivors (the baseline's re-run
+                # assumption)
+                masked = []
+                for c in survivors:
+                    leaves_c = jax.tree_util.tree_leaves(deltas[c])
+                    masked.append([
+                        dense_masked_update(x, sa, c, survivors, state.round,
+                                            i)
+                        for i, x in enumerate(leaves_c)
+                    ])
+                summed = [
+                    sum(m[i] for m in masked) / len(survivors)
+                    for i in range(len(leaves))
+                ]
+                agg = jax.tree_util.tree_unflatten(
+                    jax.tree_util.tree_structure(state.params),
+                    [s.astype(d) for s, d in zip(summed, leaf_dtypes)],
+                )
+            else:
+                agg = jax.tree_util.tree_map(
+                    lambda *xs: sum(xs) / len(xs),
+                    *[deltas[c] for c in survivors]
+                )
+            rec = costs.dense_round_record(
+                state.round, model_size, n_clients=len(participants),
+                bits=bits, n_survivors=len(survivors))
+
+        for ci, c in enumerate(participants):
+            state.losses[c] = losses_list[ci]
+        state.params = jax.tree_util.tree_map(
+            lambda p, d: p + fed.server_lr * d, state.params, agg
+        )
+        state.comm_log.append(rec)
+        state.round += 1
     return state
 
 
@@ -564,83 +591,98 @@ def run_async_update(
     one buffer must be distinct: error-feedback residual write-back is
     per-client, and a duplicate's first report would be silently clobbered.
     """
-    if thgs is None:
-        raise ValueError("run_async_update requires THGS sparse streams")
-    if topology not in ("flat", "tree"):
-        raise ValueError(f"unknown topology {topology!r}")
-    participants = sorted(client_batches.keys())
-    B = len(participants)
-    assert len(set(participants)) == B, "buffer clients must be distinct"
-    staleness = staleness or {}
-    taus = [int(staleness.get(c, 0)) for c in participants]
-    w_list = [staleness_weight(t) *
-              (float(client_weights.get(c, 1.0)) if client_weights else 1.0)
-              for c, t in zip(participants, taus)]
-    w_vec = jnp.asarray(w_list, jnp.float32)
-    w_total = float(sum(w_list))
+    with TraceAnnotation("fl.local_sgd"):
+        if thgs is None:
+            raise ValueError("run_async_update requires THGS sparse streams")
+        if topology not in ("flat", "tree"):
+            raise ValueError(f"unknown topology {topology!r}")
+        participants = sorted(client_batches.keys())
+        B = len(participants)
+        assert len(set(participants)) == B, "buffer clients must be distinct"
+        staleness = staleness or {}
+        taus = [int(staleness.get(c, 0)) for c in participants]
+        w_list = [staleness_weight(t) *
+                  (float(client_weights.get(c, 1.0)) if client_weights
+                   else 1.0)
+                  for c, t in zip(participants, taus)]
+        w_vec = jnp.asarray(w_list, jnp.float32)
+        w_total = float(sum(w_list))
 
-    leaves, treedef = jax.tree_util.tree_flatten(state.params)
-    leaf_shapes = [x.shape for x in leaves]
-    leaf_dtypes = [x.dtype for x in leaves]
-    model_size = sum(x.size for x in leaves)
+        leaves, treedef = jax.tree_util.tree_flatten(state.params)
+        leaf_shapes = [x.shape for x in leaves]
+        leaf_dtypes = [x.dtype for x in leaves]
+        leaf_sizes = [x.size for x in leaves]
+        model_size = sum(leaf_sizes)
 
-    # ---- 1. every report's local SGD from its own stale params ----
-    batches_stacked = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *[client_batches[c] for c in participants])
-    params_stacked = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *[client_params[c] for c in participants])
-    deltas_stacked, losses = batched_client_update_multi(
-        params_stacked, batches_stacked, loss_fn, fed.local_steps,
-        fed.local_lr, fed.prox_mu if fed.algorithm == "fedprox" else 0.0)
-    losses_list = [float(x) for x in losses]
+        # ---- 1. every report's local SGD from its own stale params ----
+        batches_stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[client_batches[c] for c in participants])
+        params_stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs),
+            *[client_params[c] for c in participants])
+        deltas_stacked, losses = batched_client_update_multi(
+            params_stacked, batches_stacked, loss_fn, fed.local_steps,
+            fed.local_lr, fed.prox_mu if fed.algorithm == "fedprox" else 0.0)
+    with TraceAnnotation("fl.host_read", values=B):
+        losses_list = [float(x) for x in losses]
 
-    loss_prev = _mean_or_none([state.losses.get(c) for c in participants])
-    loss_curr = _mean_or_none(losses_list)
-    ks = schedules.leaf_ks(
-        thgs, [x.size for x in leaves], t=state.round,
-        total_rounds=fed.rounds, loss_prev=loss_prev, loss_curr=loss_curr)
-    groups = tree_groups if tree_groups > 0 else max(2, int(round(B ** 0.5)))
+    with TraceAnnotation("fl.schedule"):
+        loss_prev = _mean_or_none([state.losses.get(c) for c in participants])
+        loss_curr = _mean_or_none(losses_list)
+        ks = schedules.leaf_ks(
+            thgs, leaf_sizes, t=state.round,
+            total_rounds=fed.rounds, loss_prev=loss_prev,
+            loss_curr=loss_curr)
 
-    delta_leaves = jax.tree_util.tree_leaves(deltas_stacked)
-    res_per_client = [jax.tree_util.tree_leaves(state.residuals[c])
-                      for c in participants]
-    res_stacked = [jnp.stack([rl[i] for rl in res_per_client])
-                   for i in range(len(leaves))]
+    with TraceAnnotation("fl.restack"):
+        groups = tree_groups if tree_groups > 0 else max(
+            2, int(round(B ** 0.5)))
+        delta_leaves = jax.tree_util.tree_leaves(deltas_stacked)
+        res_per_client = [jax.tree_util.tree_leaves(state.residuals[c])
+                          for c in participants]
+        res_stacked = [jnp.stack([rl[i] for rl in res_per_client])
+                       for i in range(len(leaves))]
 
     agg_leaves, new_res_leaves = [], []
-    ks_acct, leaf_sizes_acct = [], []
-    for leaf_id, (d_st, r_st, k, shape) in enumerate(
-            zip(delta_leaves, res_stacked, ks, leaf_shapes)):
-        size = leaves[leaf_id].size
+    for leaf_id, (d_st, r_st, k, size, shape) in enumerate(
+            zip(delta_leaves, res_stacked, ks, leaf_sizes, leaf_shapes)):
         # ---- 2. batched unified-stream encode, staleness-weighted ----
-        streams_b, new_res = se.encode_leaf_batch(
-            d_st, r_st, k=k, nb=1, m=size, size=size,
-            selector=thgs.selector, sample_frac=thgs.sample_frac,
-            leaf_id=leaf_id, weights=w_vec, codec=codec)
+        with TraceAnnotation("fl.encode", leaf=leaf_id):
+            streams_b, new_res = se.encode_leaf_batch(
+                d_st, r_st, k=k, nb=1, m=size, size=size,
+                selector=thgs.selector, sample_frac=thgs.sample_frac,
+                leaf_id=leaf_id, weights=w_vec, codec=codec)
         # ---- 3. fused decode (flat or hierarchical) ----
-        if topology == "tree":
-            dense = se.decode_leaf_tree(
-                streams_b, nb=1, m=size, size=size,
-                splits=se.tree_splits(size, groups))
-        else:
-            dense = se.decode_leaf_batch(streams_b, nb=1, m=size, size=size)
-        agg_leaves.append(
-            (dense / w_total).reshape(shape).astype(leaf_dtypes[leaf_id]))
-        new_res_leaves.append(new_res)
-        ks_acct.append(min(int(k), size))
-        leaf_sizes_acct.append(size)
+        with TraceAnnotation("fl.decode", leaf=leaf_id):
+            if topology == "tree":
+                dense = se.decode_leaf_tree(
+                    streams_b, nb=1, m=size, size=size,
+                    splits=se.tree_splits(size, groups))
+            else:
+                dense = se.decode_leaf_batch(streams_b, nb=1, m=size,
+                                             size=size)
+            agg_leaves.append(
+                (dense / w_total).reshape(shape).astype(leaf_dtypes[leaf_id]))
+        with TraceAnnotation("fl.residuals"):
+            new_res_leaves.append(new_res)
 
-    agg = jax.tree_util.tree_unflatten(treedef, agg_leaves)
-    for ci, c in enumerate(participants):
-        state.residuals[c] = jax.tree_util.tree_unflatten(
-            treedef, [nr[ci] for nr in new_res_leaves])
-        state.losses[c] = losses_list[ci]
-    rec = costs.round_record(
-        state.round, model_size, ks_acct, [0] * len(ks_acct),
-        n_clients=B, bits=bits, n_survivors=B, threshold=0,
-        codec=codec, leaf_sizes=leaf_sizes_acct, staleness=tuple(taus))
-    state.params = jax.tree_util.tree_map(
-        lambda p, d: p + fed.server_lr * d, state.params, agg)
-    state.comm_log.append(rec)
-    state.round += 1
+    with TraceAnnotation("fl.residuals"):
+        for ci, c in enumerate(participants):
+            state.residuals[c] = jax.tree_util.tree_unflatten(
+                treedef, [nr[ci] for nr in new_res_leaves])
+    with TraceAnnotation("fl.server_update"):
+        agg = jax.tree_util.tree_unflatten(treedef, agg_leaves)
+        for ci, c in enumerate(participants):
+            state.losses[c] = losses_list[ci]
+        rec = costs.round_record(
+            state.round, model_size,
+            [min(int(k), size) for k, size in zip(ks, leaf_sizes)],
+            [0] * len(ks), n_clients=B, bits=bits, n_survivors=B,
+            threshold=0, codec=codec, leaf_sizes=leaf_sizes,
+            staleness=tuple(taus))
+        state.params = jax.tree_util.tree_map(
+            lambda p, d: p + fed.server_lr * d, state.params, agg)
+        state.comm_log.append(rec)
+        state.round += 1
     return state

@@ -43,6 +43,7 @@ from typing import Mapping, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import masks
 from repro.core.types import SecureAggConfig
@@ -75,24 +76,26 @@ class RoundProtocol:
     def setup(cls, sa: SecureAggConfig, participants: Sequence[int],
               round_t: int) -> "RoundProtocol":
         """Phases 0-1: advertise key pairs, Shamir-share the private keys."""
-        parts = tuple(sorted(int(c) for c in participants))
-        if len(set(parts)) != len(parts):
-            raise ValueError(f"duplicate participant ids: {parts}")
-        if len(parts) < 2:
-            raise ValueError("secure aggregation needs >= 2 participants")
-        t = sa.t_for(len(parts))
-        publics = {}
-        shares = {}
-        privs = {}
-        points = [u + 1 for u in parts]
-        for u in parts:
-            x_u = masks.dh_private(sa.seed, u)
-            privs[u] = x_u
-            publics[u] = masks.dh_public(x_u)
-            shares[u] = shamir.share(
-                x_u, points, t, tag=f"{sa.seed}:{u}:{round_t}")
-        return cls(sa=sa, participants=parts, round_t=round_t, t=t,
-                   publics=publics, shares=shares, privs=privs)
+        n = len(participants)
+        with TraceAnnotation("fl.secagg.setup", shares=n * (n - 1)):
+            parts = tuple(sorted(int(c) for c in participants))
+            if len(set(parts)) != len(parts):
+                raise ValueError(f"duplicate participant ids: {parts}")
+            if len(parts) < 2:
+                raise ValueError("secure aggregation needs >= 2 participants")
+            t = sa.t_for(len(parts))
+            publics = {}
+            shares = {}
+            privs = {}
+            points = [u + 1 for u in parts]
+            for u in parts:
+                x_u = masks.dh_private(sa.seed, u)
+                privs[u] = x_u
+                publics[u] = masks.dh_public(x_u)
+                shares[u] = shamir.share(
+                    x_u, points, t, tag=f"{sa.seed}:{u}:{round_t}")
+            return cls(sa=sa, participants=parts, round_t=round_t, t=t,
+                       publics=publics, shares=shares, privs=privs)
 
     # ------------------------------------------------------------ data plane
     def pair_seed_matrix(self):
@@ -108,9 +111,10 @@ class RoundProtocol:
         (the protocol-free engine entry point).
         """
         parts = self.participants
-        return masks.seed_matrix_from_keys(
-            parts, [self.privs[u] for u in parts],
-            [self.publics[u] for u in parts], self.round_t)
+        with TraceAnnotation("fl.secagg.pair_seeds"):
+            return masks.seed_matrix_from_keys(
+                parts, [self.privs[u] for u in parts],
+                [self.publics[u] for u in parts], self.round_t)
 
     # -------------------------------------------------------------- recovery
     def recover_seeds(self, survivors: Sequence[int],
@@ -123,36 +127,39 @@ class RoundProtocol:
         is smaller than ``t``, and ValueError when a reconstructed key does
         not match the advertised public key (a corrupted share).
         """
-        surv = sorted(int(c) for c in survivors)
-        drop = sorted(int(c) for c in dropped)
-        known = set(self.participants)
-        if not set(surv) <= known or not set(drop) <= known:
-            raise ValueError("survivors/dropped must be round participants")
-        if set(surv) & set(drop):
-            raise ValueError("a client cannot both survive and drop")
-        if len(surv) < self.t:
-            raise ThresholdError(
-                f"{len(surv)} survivors < threshold t={self.t}: "
-                "the dropped clients' masks cannot be reconstructed")
-        pos = {u: i for i, u in enumerate(self.participants)}
-        C = len(self.participants)
-        seeds = np.zeros((C, C), np.uint32)
-        for d in drop:
-            # the server queries exactly t survivors for their shares of d's
-            # key — that is the recovery traffic costs.recovery_upload_bits
-            # charges
-            pts = {v + 1: self.shares[d][v + 1] for v in surv[:self.t]}
-            x_d = shamir.reconstruct(pts)
-            if masks.dh_public(x_d) != self.publics[d]:
+        with TraceAnnotation("fl.secagg.recover",
+                             shares=self.t * len(dropped)):
+            surv = sorted(int(c) for c in survivors)
+            drop = sorted(int(c) for c in dropped)
+            known = set(self.participants)
+            if not set(surv) <= known or not set(drop) <= known:
                 raise ValueError(
-                    f"reconstructed key of client {d} fails the public-key "
-                    "check — corrupted share?")
-            for s in surv:
-                secret = pow(self.publics[s], x_d, masks.DH_PRIME)
-                sd = masks.seed_from_secret(secret, self.round_t)
-                seeds[pos[s], pos[d]] = sd
-                seeds[pos[d], pos[s]] = sd
-        return jnp.asarray(seeds)
+                    "survivors/dropped must be round participants")
+            if set(surv) & set(drop):
+                raise ValueError("a client cannot both survive and drop")
+            if len(surv) < self.t:
+                raise ThresholdError(
+                    f"{len(surv)} survivors < threshold t={self.t}: "
+                    "the dropped clients' masks cannot be reconstructed")
+            pos = {u: i for i, u in enumerate(self.participants)}
+            C = len(self.participants)
+            seeds = np.zeros((C, C), np.uint32)
+            for d in drop:
+                # the server queries exactly t survivors for their shares of
+                # d's key — that is the recovery traffic
+                # costs.recovery_upload_bits charges
+                pts = {v + 1: self.shares[d][v + 1] for v in surv[:self.t]}
+                x_d = shamir.reconstruct(pts)
+                if masks.dh_public(x_d) != self.publics[d]:
+                    raise ValueError(
+                        f"reconstructed key of client {d} fails the "
+                        "public-key check — corrupted share?")
+                for s in surv:
+                    secret = pow(self.publics[s], x_d, masks.DH_PRIME)
+                    sd = masks.seed_from_secret(secret, self.round_t)
+                    seeds[pos[s], pos[d]] = sd
+                    seeds[pos[d], pos[s]] = sd
+            return jnp.asarray(seeds)
 
     # ------------------------------------------------------------ accounting
     @property

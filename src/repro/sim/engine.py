@@ -36,6 +36,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import checkpoint
 from repro.core import costs
@@ -307,6 +308,24 @@ class Simulation:
         return step
 
     # ------------------------------------------------------------------- run
+    def _record_round(self, r: int, state: FederatedState, batches: dict,
+                      accs: list, losses: list, **info) -> dict:
+        """Ledger entry, mean loss, eval and checkpoint after round ``r``;
+        returns the hooks' ``info``."""
+        cfg = self.cfg
+        rec = state.comm_log[-1]
+        self.ledger.record(rec)
+        loss = float(np.mean([state.losses[c] for c in batches]))
+        losses.append(loss)
+        info.update(state=state, loss=loss, record=rec)
+        if (r + 1) % max(1, cfg.eval_every) == 0:
+            acc = accuracy(self.model, state.params, self.xt, self.yt)
+            accs.append(acc)
+            info["acc"] = acc
+        if cfg.ckpt_dir and cfg.ckpt_every and (r + 1) % cfg.ckpt_every == 0:
+            self._save_ckpt(r + 1, state, accs, losses)
+        return info
+
     def run(self, *, resume: bool = True,
             hooks: Sequence[RoundHook] = ()) -> SimResult:
         cfg = self.cfg
@@ -319,36 +338,33 @@ class Simulation:
         start = self._try_resume(state, accs, losses) if resume else 0
         t0 = time.perf_counter()
         for r in range(start, cfg.rounds):
-            cohort = self.sampler.cohort_for(r)
-            # the compile-once contract: the stacked shapes never change
-            assert len(cohort) == cfg.clients_per_round, (
-                "fixed-cohort contract violated: "
-                f"{len(cohort)} != {cfg.clients_per_round}")
-            dropped = self.sampler.dropouts_for(
-                r, cohort, min_survivors=self.min_survivors)
-            batches = self._batches_for(r, cohort)
-            state = run_round(
-                state, batches, self.loss_fn, self.fed,
-                cfg.thgs, cfg.sa, bits=self.bits,
-                client_weights=self.client_weights, dropped=dropped,
-                mesh=self.mesh, codec=cfg.codec,
-                topology=cfg.topology, tree_groups=cfg.tree_groups,
-                dp=cfg.dp)
-            rec = state.comm_log[-1]
-            self.ledger.record(rec)
-            loss = float(np.mean([state.losses[c] for c in batches]))
-            losses.append(loss)
-            info = {"state": state, "cohort": cohort, "dropped": dropped,
-                    "loss": loss, "record": rec}
-            if (r + 1) % max(1, cfg.eval_every) == 0:
-                acc = accuracy(self.model, state.params, self.xt, self.yt)
-                accs.append(acc)
-                info["acc"] = acc
-            if (cfg.ckpt_dir and cfg.ckpt_every
-                    and (r + 1) % cfg.ckpt_every == 0):
-                self._save_ckpt(r + 1, state, accs, losses)
-            for hook in hooks:
-                hook(r, info)
+            with TraceAnnotation("fl.round", round=r) as round_span:
+                with TraceAnnotation("fl.engine.sample"):
+                    cohort = self.sampler.cohort_for(r)
+                    # the compile-once contract: the stacked shapes never
+                    # change
+                    assert len(cohort) == cfg.clients_per_round, (
+                        "fixed-cohort contract violated: "
+                        f"{len(cohort)} != {cfg.clients_per_round}")
+                    dropped = self.sampler.dropouts_for(
+                        r, cohort, min_survivors=self.min_survivors)
+                round_span.set_metadata(dropped=len(dropped))
+                with TraceAnnotation("fl.engine.batches"):
+                    batches = self._batches_for(r, cohort)
+                state = run_round(
+                    state, batches, self.loss_fn, self.fed,
+                    cfg.thgs, cfg.sa, bits=self.bits,
+                    client_weights=self.client_weights, dropped=dropped,
+                    mesh=self.mesh, codec=cfg.codec,
+                    topology=cfg.topology, tree_groups=cfg.tree_groups,
+                    dp=cfg.dp)
+                with TraceAnnotation("fl.engine.record"):
+                    info = self._record_round(r, state, batches, accs,
+                                              losses, cohort=cohort,
+                                              dropped=dropped)
+                with TraceAnnotation("fl.engine.hooks"):
+                    for hook in hooks:
+                        hook(r, info)
         self.state = state
         return SimResult(
             name=cfg.name,
@@ -435,38 +451,34 @@ class AsyncSimulation(Simulation):
         start = self._try_resume(state, accs, losses) if resume else 0
         t0 = time.perf_counter()
         for r in range(start, cfg.rounds):
-            cohort = self.sampler.cohort_for(r)
-            assert len(cohort) == self.buffer, (
-                "fixed-buffer contract violated: "
-                f"{len(cohort)} != {self.buffer}")
-            taus = self._staleness_for(r)
-            batches = self._batches_for(r, cohort)
-            client_params = {int(c): self.versions[-1 - tau]
-                             for c, tau in zip(cohort, taus)}
-            state = run_async_update(
-                state, batches, client_params, self.loss_fn, self.fed,
-                cfg.thgs, bits=self.bits,
-                staleness={int(c): tau for c, tau in zip(cohort, taus)},
-                client_weights=self.client_weights, codec=cfg.codec,
-                topology=cfg.topology, tree_groups=cfg.tree_groups)
-            self.versions.append(state.params)
-            if len(self.versions) > cfg.max_staleness + 1:
-                self.versions = self.versions[-(cfg.max_staleness + 1):]
-            rec = state.comm_log[-1]
-            self.ledger.record(rec)
-            loss = float(np.mean([state.losses[c] for c in batches]))
-            losses.append(loss)
-            info = {"state": state, "cohort": cohort, "dropped": (),
-                    "staleness": taus, "loss": loss, "record": rec}
-            if (r + 1) % max(1, cfg.eval_every) == 0:
-                acc = accuracy(self.model, state.params, self.xt, self.yt)
-                accs.append(acc)
-                info["acc"] = acc
-            if (cfg.ckpt_dir and cfg.ckpt_every
-                    and (r + 1) % cfg.ckpt_every == 0):
-                self._save_ckpt(r + 1, state, accs, losses)
-            for hook in hooks:
-                hook(r, info)
+            with TraceAnnotation("fl.round", round=r, dropped=0):
+                with TraceAnnotation("fl.engine.sample"):
+                    cohort = self.sampler.cohort_for(r)
+                    assert len(cohort) == self.buffer, (
+                        "fixed-buffer contract violated: "
+                        f"{len(cohort)} != {self.buffer}")
+                    taus = self._staleness_for(r)
+                    client_params = {int(c): self.versions[-1 - tau]
+                                     for c, tau in zip(cohort, taus)}
+                with TraceAnnotation("fl.engine.batches"):
+                    batches = self._batches_for(r, cohort)
+                state = run_async_update(
+                    state, batches, client_params, self.loss_fn, self.fed,
+                    cfg.thgs, bits=self.bits,
+                    staleness={int(c): tau for c, tau in zip(cohort, taus)},
+                    client_weights=self.client_weights, codec=cfg.codec,
+                    topology=cfg.topology, tree_groups=cfg.tree_groups)
+                with TraceAnnotation("fl.engine.record"):
+                    self.versions.append(state.params)
+                    if len(self.versions) > cfg.max_staleness + 1:
+                        self.versions = self.versions[
+                            -(cfg.max_staleness + 1):]
+                    info = self._record_round(r, state, batches, accs,
+                                              losses, cohort=cohort,
+                                              dropped=(), staleness=taus)
+                with TraceAnnotation("fl.engine.hooks"):
+                    for hook in hooks:
+                        hook(r, info)
         self.state = state
         return SimResult(
             name=cfg.name,
