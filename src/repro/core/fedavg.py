@@ -408,9 +408,14 @@ def run_round(
                         leaf_id=leaf_id, weights=w_vec, codec=codec,
                         dp_sigma=dp_sigma_c, dp_seeds=dp_seeds,
                         dp_support_seed=dp_sup_seed)
+            splits = (se.tree_splits(size, groups) if topology == "tree"
+                      else ())
             with TraceAnnotation(
                     "fl.encode_decode" if sharded else "fl.decode",
-                    leaf=leaf_id):
+                    leaf=leaf_id,
+                    scatter_steps=se.scatter_steps(
+                        C, k, k_mask, 1, size, recovery=bool(dropped),
+                        splits=splits)):
                 if sharded:
                     # ---- 2+3. client-parallel encode + fused decode: one
                     # shard_map program per leaf (DESIGN.md §11) ----
@@ -428,8 +433,7 @@ def run_round(
                 # ---- 3. fused scatter-add decode + dropout recovery ----
                 elif topology == "tree":
                     dense = se.decode_leaf_tree(
-                        streams_b, nb=1, m=size, size=size,
-                        splits=se.tree_splits(size, groups),
+                        streams_b, nb=1, m=size, size=size, splits=splits,
                         alive=alive if dropped else None,
                         pair_seeds=recovery_seeds if dropped else None,
                         pair_signs=pair_signs if dropped else None,
@@ -654,11 +658,14 @@ def run_async_update(
                 selector=thgs.selector, sample_frac=thgs.sample_frac,
                 leaf_id=leaf_id, weights=w_vec, codec=codec)
         # ---- 3. fused decode (flat or hierarchical) ----
-        with TraceAnnotation("fl.decode", leaf=leaf_id):
+        splits = se.tree_splits(size, groups) if topology == "tree" else ()
+        with TraceAnnotation(
+                "fl.decode", leaf=leaf_id,
+                scatter_steps=se.scatter_steps(B, k, 0, 1, size,
+                                               splits=splits)):
             if topology == "tree":
                 dense = se.decode_leaf_tree(
-                    streams_b, nb=1, m=size, size=size,
-                    splits=se.tree_splits(size, groups))
+                    streams_b, nb=1, m=size, size=size, splits=splits)
             else:
                 dense = se.decode_leaf_batch(streams_b, nb=1, m=size,
                                              size=size)

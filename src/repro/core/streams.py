@@ -777,18 +777,45 @@ def tree_splits(padded: int, n_groups: int) -> tuple[int, ...]:
     return tuple(bounds)
 
 
+def scatter_steps(n_clients: int, k: int, k_mask: int, nb: int, m: int, *,
+                  recovery: bool = False, splits: Sequence[int] = ()) -> int:
+    """Grid steps of the Pallas decode for one leaf's round stream: every
+    client's ``min(k, m) + C*k_mask`` slots per block (``encode_leaf_batch``)
+    plus, with ``recovery``, the ``C*C*k_mask`` Bonawitz streams per block.
+    One kernel call for the flat decode; one per non-empty range of
+    ``splits`` for the tree decode, each over the whole stream."""
+    from repro.kernels.stream_decode import grid_steps
+
+    C = n_clients
+    n = C * nb * (min(int(k), m) + C * k_mask) + (
+        C * C * nb * k_mask if recovery else 0)
+    if not splits:
+        return grid_steps(n, nb * m)
+    return sum(grid_steps(n, hi - lo)
+               for lo, hi in zip(splits[:-1], splits[1:]) if hi > lo)
+
+
 def _scatter_range(flat_idx: jax.Array, flat_vals: jax.Array,
                    lo: int, hi: int, use_pallas: bool) -> jax.Array:
     """One sub-aggregator's partial: scatter the slots landing in
     ``[lo, hi)`` of the padded buffer, in the round stream's slot order.
 
-    Out-of-range slots are redirected to a dump slot at position ``width``
-    (buffer ``width + 1``, sliced off on return) with value 0.0 — NOT zeroed
-    in place: an in-range position must never receive a redirected ``+0.0``
-    (``-0.0 + 0.0 == +0.0`` would flip the sign bit of a ``-0.0`` partial
-    and break bit-exactness with the flat scatter).
+    The Pallas kernel is handed the whole stream shifted by ``lo`` and drops
+    the slots outside ``[0, width)`` itself: its sort then orders the slots
+    as the flat decode's does, and its chunk windows (global to the sorted
+    stream) group each position's slots into the same contractions, so the
+    partial is bit-exact with the flat scatter (kernels/stream_decode.py).
+
+    On the XLA scatter, out-of-range slots are redirected to a dump slot at
+    position ``width`` (buffer ``width + 1``, sliced off on return) with
+    value 0.0 — NOT zeroed in place: an in-range position must never
+    receive a redirected ``+0.0`` (``-0.0 + 0.0 == +0.0`` would flip the
+    sign bit of a ``-0.0`` partial and break bit-exactness with the flat
+    scatter).
     """
     width = hi - lo
+    if use_pallas:
+        return _scatter_flat(flat_idx - lo, flat_vals, width, use_pallas)
     in_range = (flat_idx >= lo) & (flat_idx < hi)
     local = jnp.where(in_range, flat_idx - lo, width)
     vals = jnp.where(in_range, flat_vals, 0.0)

@@ -196,6 +196,57 @@ def test_tree_decode_bitexact_all_codecs(codec):
         np.testing.assert_array_equal(np.asarray(flat), np.asarray(tree))
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+def test_tree_decode_bitexact_pallas(seed):
+    """The Pallas decode (interpret mode here) sorts the stream and groups it
+    in chunk windows global to the sorted stream, so tree == flat holds on
+    it bit for bit too: heavy duplicates across a tile boundary, negative
+    values, recovery slots, a dropped client's gate, and uneven splits that
+    cut tiles part-way. The XLA-path cases above keep their dump slot."""
+    C, tile = 5, 64 * 128
+    size, k, k_rec = 3 * tile + 700, 900, 300
+    key = jax.random.key(seed)
+    hot = jax.random.randint(key, (C, 1, k // 2), tile - 200, tile + 200)
+    wide = jax.random.randint(jax.random.fold_in(key, 1),
+                              (C, 1, k - k // 2), 0, size)
+    stb = se.StreamBatch(
+        indices=jnp.concatenate([hot, wide], axis=-1),
+        values=jax.random.normal(jax.random.fold_in(key, 2), (C, 1, k)))
+    extra = se.StreamBatch(
+        indices=jax.random.randint(jax.random.fold_in(key, 3),
+                                   (C * C, 1, k_rec), tile - 300, size),
+        values=-jax.random.normal(jax.random.fold_in(key, 4),
+                                  (C * C, 1, k_rec)))
+    alive = jnp.asarray([True, False, True, True, True])
+    weights = jax.random.uniform(jax.random.fold_in(key, 5), (C,),
+                                 minval=0.5, maxval=2.0)
+    kw = dict(alive=alive, weights=weights, extra=extra)
+    flat = se.decode_sum_blocks(stb, 1, size, use_pallas=True, **kw)
+    np.testing.assert_allclose(
+        np.asarray(flat),
+        np.asarray(se.decode_sum_blocks(stb, 1, size, use_pallas=False,
+                                        **kw)), rtol=1e-5, atol=1e-5)
+    for splits in [(0, 5000, tile, tile + 1, 2 * tile + 77, size),
+                   se.tree_splits(size, 3)]:
+        tree = se.decode_sum_tree(stb, 1, size, splits=splits,
+                                  use_pallas=True, **kw)
+        np.testing.assert_array_equal(np.asarray(flat), np.asarray(tree))
+
+
+def test_scatter_steps_counts_each_range():
+    """The span stat ``scatter_steps``: the tree decode runs the kernel once
+    per non-empty range, each call over the whole round stream."""
+    from repro.kernels.stream_decode import grid_steps
+
+    C, k, k_mask, size = 4, 300, 20, 3 * 64 * 128 + 5
+    n = C * (k + C * k_mask) + C * C * k_mask
+    assert se.scatter_steps(C, k, k_mask, 1, size, recovery=True) == \
+        grid_steps(n, size)
+    assert se.scatter_steps(C, k, k_mask, 1, size, recovery=True,
+                            splits=(0, 100, 100, 9000, size)) == sum(
+        grid_steps(n, w) for w in (100, 8900, size - 9000))
+
+
 # ------------------------------------------------------------- round parity
 def _one_round(topology, tree_groups, dropped):
     from repro.models.paper_models import PAPER_MODELS, cross_entropy_loss

@@ -129,17 +129,88 @@ def test_pair_mask_streams_opposite_signs_cancel_bitwise():
     assert float(jnp.max(jnp.abs(vals[0] + vals[1]))) == 0.0
 
 
-@pytest.mark.parametrize("n,size", [(100, 1000), (700, 257), (2048, 100_000),
-                                    (5, 64)])
-def test_stream_scatter_add_matches_ref(n, size):
-    k1, k2 = jax.random.split(jax.random.fold_in(KEY, 20))
-    # include duplicates, the -1 padding sentinel, and out-of-range indices
-    idx = jax.random.randint(k1, (n,), -2, size + 3)
+TILE = 64 * 128          # dense entries per tile of the decode kernel
+
+
+def _scatter_case(pattern, n, size, key):
+    """(indices, values) of one decode case; ``random`` draws duplicates,
+    the -1 padding sentinel and out-of-range indices."""
+    k1, k2, k3 = jax.random.split(key, 3)
     val = jax.random.normal(k2, (n,))
+    if pattern == "random":
+        return jax.random.randint(k1, (n,), -2, size + 3), val
+    if pattern == "one_tile":          # all slots in tile 2 of 5
+        return jax.random.randint(k1, (n,), 2 * TILE, 3 * TILE), val
+    if pattern == "long_run":          # one index repeated past a chunk
+        run = jnp.full((1200,), 777, jnp.int32)
+        rest = jax.random.randint(k1, (n - 1200,), 0, size)
+        return jax.random.permutation(k3, jnp.concatenate([run, rest])), val
+    if pattern == "straddle":          # sorted chunk 1 spans tiles 0, 1, 2
+        parts = [jax.random.randint(jax.random.fold_in(k1, t), (c,),
+                                    t * TILE, (t + 1) * TILE)
+                 for t, c in enumerate((600, 100, 324))]
+        return jax.random.permutation(k3, jnp.concatenate(parts)), val
+    if pattern == "pad_and_oob":       # -1 padding, indices at/above size
+        odd = jnp.asarray([-1] * 40 + [size] * 20 + [size + 7] * 10
+                          + [TILE - 1, TILE, 5 * TILE], jnp.int32)
+        rest = jax.random.randint(k1, (n - odd.shape[0],), 0, size)
+        return jax.random.permutation(k3, jnp.concatenate([odd, rest])), val
+    raise ValueError(pattern)
+
+
+@pytest.mark.parametrize("n,size,pattern", [
+    pytest.param(100, 1000, "random", id="100-1000"),
+    pytest.param(700, 257, "random", id="700-257"),
+    pytest.param(2048, 100_000, "random", id="2048-100000"),
+    pytest.param(5, 64, "random", id="5-64"),
+    pytest.param(3000, 5 * TILE, "one_tile", id="one_tile"),
+    pytest.param(2000, 3 * TILE, "long_run", id="long_run"),
+    pytest.param(1024, 4 * TILE, "straddle", id="chunk_straddles_3_tiles"),
+    pytest.param(700, 3 * TILE - 100, "pad_and_oob", id="pad_and_oob"),
+    pytest.param(37, 20_000, "random", id="n_below_chunk"),
+    pytest.param(2000, TILE, "random", id="one_tile_buffer"),
+])
+def test_stream_scatter_add_matches_ref(n, size, pattern):
+    idx, val = _scatter_case(pattern, n, size,
+                             jax.random.fold_in(KEY, 20))
     out = ops.stream_scatter_add(idx, val, size=size)
     exp = ref.stream_scatter_add_ref(idx, val, size)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), rtol=1e-5,
                                atol=1e-5)
+    if pattern == "one_tile":
+        dense = np.asarray(out)
+        assert not dense[:2 * TILE].any() and not dense[3 * TILE:].any()
+
+
+@pytest.mark.parametrize("n,size", [(3000, 5 * TILE), (1024, 4 * TILE),
+                                    (37, 20_000), (2000, TILE),
+                                    (100_000, 40 * TILE)])
+def test_stream_scatter_add_work_list(n, size):
+    """The sorted stream's (tile, chunk) list: of length ``grid_steps``,
+    monotone in the tile and in the chunk, every tile visited, its live
+    items exactly the pairs that share a slot."""
+    from repro.kernels import stream_decode as sd
+
+    chunk = 512
+    steps = sd.grid_steps(n, size)
+    n_tiles = -(-size // TILE)
+    n_chunks = -(-n // chunk)
+    assert steps == n_chunks + n_tiles
+    idx = jax.random.randint(jax.random.fold_in(KEY, 21), (n,), -1, size)
+    idx = jnp.sort(jnp.pad(idx, (0, n_chunks * chunk - n),
+                           constant_values=-1))
+    tile_of, chunk_of, live = map(np.asarray, sd.work_list(
+        idx, n_tiles, TILE, chunk, steps))
+    assert tile_of.shape == chunk_of.shape == live.shape == (steps,)
+    assert (np.diff(tile_of) >= 0).all() and (np.diff(chunk_of) >= 0).all()
+    assert set(tile_of.tolist()) == set(range(n_tiles))
+    assert live.sum() <= n_chunks + n_tiles
+    tiles = np.asarray(idx).reshape(n_chunks, chunk) // TILE
+    overlap = {(int(t), c) for c in range(n_chunks) for t in tiles[c]
+               if 0 <= t < n_tiles}
+    items = {(int(t), int(c)) for t, c, ok in zip(tile_of, chunk_of, live)
+             if ok}
+    assert items == overlap
 
 
 def test_stream_scatter_add_duplicates_accumulate():

@@ -65,12 +65,17 @@ def traced(sim, tmp) -> list:
 
 
 @pytest.fixture(scope="module")
-def sync_run(tmp_path_factory):
+def sync_sim(tmp_path_factory):
     sim = Simulation(_SYNC)
     # one dropped client a round, after mask agreement: recovery runs
     sim.sampler.dropouts_for = (
         lambda r, cohort, min_survivors=1: [int(cohort[0])])
-    spans = traced(sim, tmp_path_factory.mktemp("sync_trace"))
+    return sim, traced(sim, tmp_path_factory.mktemp("sync_trace"))
+
+
+@pytest.fixture(scope="module")
+def sync_run(sync_sim):
+    sim, spans = sync_sim
     return spans, len(jax.tree_util.tree_leaves(sim.state.params))
 
 
@@ -117,6 +122,23 @@ def test_per_leaf_spans_and_counts(sync_run):
                          "fl.secagg.recover": {"shares": t * 1}}
 
 
+def test_decode_spans_count_scatter_steps(sync_sim):
+    """Each ``fl.decode`` span carries its leaf's decode-kernel grid: the
+    stream's chunks plus the dense buffer's tiles, the stream being every
+    client's ``k + C*k_mask`` slots and, with a drop, ``C*C*k_mask``
+    recovery slots (read back from the round's ledger facts)."""
+    from repro.kernels.stream_decode import grid_steps
+
+    sim, spans = sync_sim
+    for (_, inside), rec in zip(by_round(spans), sim.state.comm_log):
+        C, dropped = rec.n_clients, rec.n_clients != rec.n_survivors
+        want = [grid_steps(C * (k + C * km) + (C * C * km if dropped else 0),
+                           size)
+                for k, km, size in zip(rec.ks, rec.k_masks, rec.leaf_sizes)]
+        assert [s[3]["scatter_steps"] for s in inside
+                if s[0] == "fl.decode"] == want
+
+
 def test_stats_are_ints(sync_run):
     spans, _ = sync_run
     values = [v for s in spans for v in s[3].values()]
@@ -153,6 +175,8 @@ with jax.profiler.trace(sys.argv[1]):
 print(json.dumps({"names": sorted({s[0] for s in inside}),
                   "leaves": [s[3]["leaf"] for s in inside
                              if s[0] == "fl.encode_decode"],
+                  "steps": [s[3]["scatter_steps"] for s in inside
+                            if s[0] == "fl.encode_decode"],
                   "n_leaves": len(jax.tree_util.tree_leaves(
                       sim.state.params))}))
 """
@@ -172,3 +196,4 @@ def test_sharded_round_spans_encode_decode_per_leaf(tmp_path):
     assert set(doc["names"]) == (ENGINE | ROUND_STAGES | SECAGG | {
         "fl.encode_decode"}) - {"fl.encode", "fl.decode"}
     assert doc["leaves"] == list(range(doc["n_leaves"]))
+    assert len(doc["steps"]) == doc["n_leaves"] and min(doc["steps"]) >= 2
