@@ -9,10 +9,11 @@ window round through the benchmark's own path, and its compared rounds are
 held against the plain reference: the lower readings. For every seed of
 ``--control-seeds`` (each also in ``--seeds``, whose inputs it reuses) the
 control, the reference computed in bfloat16, and each planted fault of the
-reference (``reference.FAULTS``) are held against the float32 reference: the
-upper readings. A state left unchanged reads 1 on ``change_n`` by
-construction and needs no run. All of it runs in this one process, at the
-cell's own size.
+reference (``reference.FAULTS``) are put in the program's place and held
+against the float32 reference, free-running and teacher-forced on their own
+state: the upper readings. A state left unchanged reads 1 on ``change_n``
+and ``agg_update`` by construction and needs no run. All of it runs in this
+one process, at the cell's own size.
 """
 from __future__ import annotations
 

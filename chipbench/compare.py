@@ -17,6 +17,36 @@ reference is under a thousandth of the median leaf's (conv biases ahead of a
 BatchNorm, whose gradient is nought up to rounding) are left out of all of
 them by that rule, not by name. A cell's ``limits/<cell>.json`` names the
 numbers it compares.
+
+The teacher-forced numbers hold each compared round of the program against
+``reference.forced``, which repeats that round from the program's own state
+at its start (its params, and for the aggregation its deltas and
+residuals), so that they read each stage before rounding grows:
+
+* ``agg_update``, ``agg_update_median``: each round's server update (the
+  params after it minus those before it) against the reference's
+  aggregation of the program's deltas, leaf by leaf as above, worst round;
+* ``agg_residual_median``: the cohort's residuals after each round, median
+  leaf, worst round;
+* ``sgd_delta_median``: the cohort's local-SGD deltas, median leaf, worst
+  round.
+
+Each round's negligible leaves follow the same rule on that round's
+reference update (for the deltas: on the reference's deltas).
+
+Which numbers a kind of cell compares, and why (readings in PERF.md):
+
+* an MLP cell (``mlp_t2_secagg_drop``), whose local SGD keeps float32
+  rounding small over a round, compares the free-running ``loss1`` and
+  medians: the bfloat16 control fails ``loss1``, each fault a median;
+* VGG16 with BatchNorm grows float32 rounding to about 1e-2 within a
+  round's local steps, so no number read after local SGD tells the control
+  from a sound run. The teacher-forced ``agg_update_median`` does: a sound
+  aggregation agrees to rounding (about 1e-8), the control and a lost
+  upload read four to six orders of magnitude higher. ``sgd_delta_median``
+  catches a half batch, but not local SGD run in bfloat16 alone: that
+  needs the loss of a single local step, which the program does not
+  report. So a VGG16 cell is not in the benchmark yet.
 """
 from __future__ import annotations
 
@@ -25,7 +55,9 @@ import math
 import numpy as np
 
 NUMBERS = ("loss1", "loss", "update1", "change_n", "residual_n",
-           "update1_median", "change_n_median", "residual_n_median")
+           "update1_median", "change_n_median", "residual_n_median",
+           "agg_update", "agg_update_median", "agg_residual_median",
+           "sgd_delta_median")
 NEGLIGIBLE = 1e-3
 
 
@@ -49,14 +81,48 @@ def _median(gaps) -> float:
         np.median(gaps))
 
 
-def numbers(prog, ref) -> dict:
-    """Both sides are ``reference.Outputs`` (the program's built from what
-    its run produced)."""
-    upd = {side: {n: _norm(o.params1[n] - o.params0[n]) for n in o.params0}
+def _kept(ref_norms: dict) -> list:
+    """The leaves whose reference norm is not under ``NEGLIGIBLE`` of the
+    median leaf's."""
+    median = float(np.median(list(ref_norms.values())))
+    return [n for n, v in ref_norms.items() if v >= NEGLIGIBLE * median]
+
+
+def _forced(prog, forced: list) -> dict:
+    """The teacher-forced numbers (module docstring); ``forced`` holds
+    ``reference.forced``'s record of each of ``prog.rounds``."""
+    starts = [prog.params0] + [rd["params"] for rd in prog.rounds[:-1]]
+    upd, upd_med, res_med, delta_med = [], [], [], []
+    for start, mine, theirs in zip(starts, prog.rounds, forced):
+        sides = (("prog", mine), ("ref", theirs))
+        u = {side: {n: _norm(o["params"][n] - start[n]) for n in start}
+             for side, o in sides}
+        keep = _kept(u["ref"])
+        gaps = _leaf_gaps(u["prog"], u["ref"], keep)
+        upd.append(_worst(gaps))
+        upd_med.append(_median(gaps))
+        r = {side: {n: _norm(o["residuals"][n]) for n in start}
+             for side, o in sides}
+        res_med.append(_median(_leaf_gaps(r["prog"], r["ref"], keep)))
+        d = {side: {n: _norm(o["deltas"][n]) for n in start}
+             for side, o in sides}
+        delta_med.append(_median(_leaf_gaps(d["prog"], d["ref"],
+                                            _kept(d["ref"]))))
+    return {"agg_update": _worst(upd), "agg_update_median": _worst(upd_med),
+            "agg_residual_median": _worst(res_med),
+            "sgd_delta_median": _worst(delta_med)}
+
+
+def numbers(prog, ref, forced: list) -> dict:
+    """``prog`` and ``ref`` are ``reference.Outputs`` (the program's built
+    from what its run produced), ``forced`` is ``reference.forced`` on
+    ``prog``."""
+    upd = {side: {n: _norm(o.rounds[0]["params"][n] - o.params0[n])
+                  for n in o.params0}
            for side, o in (("prog", prog), ("ref", ref))}
-    median = float(np.median(list(upd["ref"].values())))
-    keep = [n for n, v in upd["ref"].items() if v >= NEGLIGIBLE * median]
-    chg = {side: {n: _norm(o.params_n[n] - o.params0[n]) for n in o.params0}
+    keep = _kept(upd["ref"])
+    chg = {side: {n: _norm(o.rounds[-1]["params"][n] - o.params0[n])
+                  for n in o.params0}
            for side, o in (("prog", prog), ("ref", ref))}
     res = {side: {n: _norm(o.residuals[n]) for n in o.residuals}
            for side, o in (("prog", prog), ("ref", ref))}
@@ -69,6 +135,7 @@ def numbers(prog, ref) -> dict:
         gaps = _leaf_gaps(per_side["prog"], per_side["ref"], keep)
         out[name] = _worst(gaps)
         out[name + "_median"] = _median(gaps)
+    out.update(_forced(prog, forced))
     return {k: out[k] for k in NUMBERS}
 
 

@@ -9,9 +9,12 @@ decode with Bonawitz recovery, the server update). A hook that
 
 1. the first rounds are recorded for the comparison with the plain
    reference (their inputs: cohort, dropped clients, batches; their outputs:
-   losses, params, residuals): ``compare_rounds`` of them, and in a mix
-   with ``compare_through_first_drop`` on to the first round with a dropped
-   client, so that recovery is compared;
+   losses, the cohort's local-SGD deltas, params, residuals):
+   ``compare_rounds`` of them, and in a mix with
+   ``compare_through_first_drop`` on to the first round with a dropped
+   client, so that recovery is compared. The deltas are taken from
+   ``fedavg.batched_client_update``, wrapped while these rounds run and put
+   back before any other round does;
 2. rounds go on until every dropped count the mix can draw has run, so
    that every program the window uses is compiled, and (in a mix with
    ``dropout_blocks``) to the end of a block; all of that is set-up;
@@ -263,6 +266,8 @@ class Driver:
                      if traffic["dropout_rate"] > 0 else {0})
         self.block = len(block) if block else 1
         self.seen, self.compare, self.losses = set(), [], []
+        self.rounds = []
+        self._deltas = None
         self.phase = "warm"
         self.round_ends, self.dropped, self.records = [], [], []
         self.spans = _Spans()
@@ -284,6 +289,19 @@ class Driver:
 
         sim._batches_for = self.spans.wrap("batches", capture_batches)
         sim._fresh_state = capture_fresh
+
+        from repro.core import fedavg
+
+        update = fedavg.batched_client_update
+
+        def capture_update(*a, **kw):
+            out = update(*a, **kw)
+            self._deltas = flat_host(out[0])
+            return out
+
+        fedavg.batched_client_update = capture_update
+        self.release = lambda: setattr(fedavg, "batched_client_update",
+                                       update)
 
     def __call__(self, r: int, info: dict) -> None:
         import jax
@@ -341,13 +359,17 @@ class Driver:
                              "dropped": [int(c) for c in info["dropped"]],
                              "batches": self._batches})
         self.losses.append(float(info["loss"]))
-        if r == 0:
-            self.params1 = flat_host(state.params)
+        cohort = sorted(int(c) for c in info["cohort"])
+        res = [flat_host(state.residuals[c]) for c in cohort]
+        self.rounds.append({
+            "deltas": self._deltas, "params": flat_host(state.params),
+            "residuals": {n: np.stack([rc[n] for rc in res])
+                          for n in res[0]}})
         dropped_yet = any(rd["dropped"] for rd in self.compare)
         if r + 1 >= self.n_compare and (
                 dropped_yet or not self.through_drop or r + 1 >= WARM_CAP):
             self.comparing = False
-            self.params_n = flat_host(state.params)
+            self.release()
             per_client = [flat_host(state.residuals[c])
                           for c in sorted(state.residuals)]
             self.residuals = {n: np.stack([pc[n] for pc in per_client])
@@ -388,6 +410,7 @@ def run_program(cell: spec.Cell, seed: int, seconds: float, t_start: float,
 
     log = CompileLog()
     saved = dict(RoundProtocol.__dict__)
+    drv = None
     try:
         sim = Simulation(sim_config(cell, seed))
         dropouts_for = block_dropouts(cell.traffic, int(seed))
@@ -400,6 +423,8 @@ def run_program(cell: spec.Cell, seed: int, seconds: float, t_start: float,
         except WindowClosed:
             pass
     finally:
+        if drv is not None:
+            drv.release()
         for name in ("setup", "recover_seeds"):
             setattr(RoundProtocol, name, saved[name])
         log.close()
@@ -414,8 +439,7 @@ def run_program(cell: spec.Cell, seed: int, seconds: float, t_start: float,
         gc_window=(drv.gc_log.count, drv.gc_log.seconds),
         memory_peak_bytes=_memory_peak(),
         prog=reference.Outputs(losses=drv.losses, params0=drv.params0,
-                               params1=drv.params1, params_n=drv.params_n,
-                               residuals=drv.residuals),
+                               residuals=drv.residuals, rounds=drv.rounds),
         compare_rounds=drv.compare,
         trace_path=None)
     if trace_dir is not None:
@@ -430,15 +454,17 @@ def run_program(cell: spec.Cell, seed: int, seconds: float, t_start: float,
 def check(cell: spec.Cell, seed: int, run: Run, dtype: str = "float32",
           fault: str | None = None) -> dict:
     """The comparison numbers of ``run`` against the plain reference (or
-    against the control / a planted fault, for calibration)."""
-    ref = reference.run(cell.reference, cell.config, cell.traffic,
-                        program_seed(seed), int(seed), run.compare_rounds)
-    if dtype == "float32" and fault is None:
-        return compare.numbers(run.prog, ref)
-    other = reference.run(cell.reference, cell.config, cell.traffic,
-                          program_seed(seed), int(seed), run.compare_rounds,
-                          dtype=dtype, fault=fault)
-    return compare.numbers(other, ref)
+    of the control / a planted fault put in the program's place, for
+    calibration): free-running and teacher-forced."""
+    model = (cell.reference, cell.config, cell.traffic)
+    ref = reference.run(*model, program_seed(seed), int(seed),
+                        run.compare_rounds)
+    other = run.prog
+    if dtype != "float32" or fault is not None:
+        other = reference.run(*model, program_seed(seed), int(seed),
+                              run.compare_rounds, dtype=dtype, fault=fault)
+    forced = reference.forced(*model, int(seed), run.compare_rounds, other)
+    return compare.numbers(other, ref, forced)
 
 
 def nearest_rank(values, q: float) -> float:

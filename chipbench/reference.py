@@ -10,7 +10,8 @@ the paper's description and the repository's documented wire format:
   entropy, at ``Precision.HIGHEST``;
 * THGS encode (paper Alg. 1, Eq. 1): per leaf, ``k`` from Eq. 1's
   per-leaf rate snapped to ``k_levels`` geometric levels, on the
-  error-feedback accumulator ``residual + delta``;
+  error-feedback accumulator ``residual + delta``; of magnitudes that tie
+  at the k-th place, the lowest indices are sent;
 * sparse pair masks (Eq. 3-5): every client also transmits its gradient
   values on the support of its pair masks toward each cohort peer. The
   support comes from the DH-agreed pair seed (SHA-256 and modular
@@ -22,6 +23,15 @@ the paper's description and the repository's documented wire format:
   number of survivors, added to the params (server lr). Transmitted
   positions leave the survivors' residuals; a dropped client keeps its whole
   accumulator.
+
+``run`` follows the rounds free-running from the seed's weights.
+``forced`` is the teacher-forced mode: it repeats each compared round in
+float32 from the state that the other side (the program, or the control or
+a fault put in its place) had at that round's start. Its local SGD starts
+from the other side's params, and its aggregation (Eq. 1 top-k, mask
+support, the survivors' sum, the server update, the residual write-back)
+runs on the other side's own deltas and residuals. A sound aggregation then
+agrees to rounding, whatever local SGD's rounding grew to over its steps.
 
 ``dtype='bfloat16'`` computes the same rounds in bfloat16 throughout: the
 control, which the comparison must refuse. ``fault`` plants one of the
@@ -138,24 +148,108 @@ def _local_sgd(params, xs, ys, *, forward, lr, half):
     return delta, jnp.mean(losses.astype(jnp.float32))
 
 
+def _clients_sgd(model, p_tree, cohort, batches, jdt, lr, half):
+    """Every cohort client's local SGD from ``p_tree``: (flat deltas per
+    client, mean loss per client)."""
+    deltas, losses = {}, []
+    for c in cohort:
+        xs, ys = batches[c]
+        d, loss = _local_sgd(p_tree, jnp.asarray(xs, jdt), jnp.asarray(ys),
+                             forward=model.forward, lr=lr, half=half)
+        deltas[c] = [np.asarray(x).reshape(-1)
+                     for x in jax.tree_util.tree_leaves(d)]
+        losses.append(float(loss))
+    return deltas, losses
+
+
+# ------------------------------------------------------------ aggregation
+def top_k(mag: np.ndarray, k: int) -> np.ndarray:
+    """The indices of the ``k`` largest entries of ``mag``; where entries
+    tie at the k-th place, the lowest indices are taken, as ``lax.top_k``
+    documents for the program's encode."""
+    kth = np.partition(mag, mag.size - k)[mag.size - k]
+    above = np.flatnonzero(mag > kth)
+    return np.concatenate([above,
+                           np.flatnonzero(mag == kth)[:k - above.size]])
+
+
+def _aggregate(params, res, deltas, cohort, dropped, ks, config, seeds,
+               ndt, fault):
+    """The server side of one round, in place on flat leaves: each client's
+    top-k on ``residual + delta`` and its mask support, the survivors' sum
+    over the number of survivors, the server update into ``params`` and the
+    residual write-back into ``res`` (a dropped client keeps its whole
+    accumulator)."""
+    sec = config["secagg"]
+    acc_dt = np.float64 if ndt is np.float32 else ndt
+    survivors = [c for c in cohort if c not in dropped]
+    lost = survivors[0] if fault == "lost_upload" else None
+    for leaf_id, size in enumerate(int(p.size) for p in params):
+        km = k_mask(sec, size, len(cohort))
+        k = min(ks[leaf_id], size)
+        agg = np.zeros(size, acc_dt)
+        for c in cohort:
+            acc = (res[c][leaf_id] + deltas[c][leaf_id]).astype(ndt)
+            if c in dropped:
+                res[c][leaf_id] = acc
+                continue
+            support = [top_k(np.abs(acc.astype(np.float32)), k)]
+            for peer in cohort:
+                if peer == c or not km:
+                    continue
+                idx, u = pair_mask(seeds[min(c, peer), max(c, peer)],
+                                   leaf_id, km, size, sec["p"], sec["q"])
+                support.append(idx)
+                if fault == "no_recovery" and peer in dropped:
+                    np.add.at(agg, idx, (u if c < peer else -u)
+                              .astype(acc_dt))
+            sup = np.unique(np.concatenate(support))
+            if c != lost:
+                agg[sup] += acc[sup].astype(acc_dt)
+            acc[sup] = 0
+            res[c][leaf_id] = acc
+        update = (agg / len(survivors)).astype(ndt)
+        params[leaf_id] = (params[leaf_id]
+                           + ndt(config["server_lr"]) * update).astype(ndt)
+
+
+def _pair_seeds(sa_seed: int, cohort: list, round_t: int) -> dict:
+    return {(a, b): pair_seed(sa_seed, a, b, round_t)
+            for i, a in enumerate(cohort) for b in cohort[i + 1:]}
+
+
 # ------------------------------------------------------------------ rounds
 @dataclasses.dataclass
 class Outputs:
     """What the comparison reads, per side: each round's mean client loss,
-    the flat params before round 1, after round 1 and after the last
-    compared round, and every client's residual after it (name -> array,
-    stacked over clients)."""
+    the flat params before round 1, every client's residual after the last
+    compared round (name -> array, stacked over clients), and one
+    ``record`` per compared round."""
 
     losses: list
     params0: dict
-    params1: dict
-    params_n: dict
     residuals: dict
+    rounds: list
 
 
 def leaf_names(tree) -> list[str]:
     return [jax.tree_util.keystr(p)
             for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def record(names, shapes, cohort, deltas, params, res) -> dict:
+    """One compared round as the comparison reads it, in float32: the
+    (sorted) cohort's local-SGD ``deltas`` and residuals after the round
+    (name -> [C, ...]), and the params after it (name -> array)."""
+    def stacked(per_client):
+        return {n: np.stack([per_client[c][i].astype(np.float32).reshape(s)
+                             for c in cohort])
+                for i, (n, s) in enumerate(zip(names, shapes))}
+
+    return {"deltas": stacked(deltas),
+            "params": {n: p.astype(np.float32).reshape(s)
+                       for n, p, s in zip(names, params, shapes)},
+            "residuals": stacked(res)}
 
 
 def run(model, config: dict, traffic: dict, seed: int, sa_seed: int,
@@ -168,77 +262,77 @@ def run(model, config: dict, traffic: dict, seed: int, sa_seed: int,
         raise ValueError(f"unknown fault {fault!r}")
     jdt = jnp.dtype(dtype)
     ndt = NP_DTYPES[dtype]
-    acc_dt = np.float64 if dtype == "float32" else ndt
     tree = model.init(jax.random.key(seed), jdt)
     flat, treedef = jax.tree_util.tree_flatten(tree)
     names = leaf_names(tree)
-    sizes = [int(x.size) for x in flat]
     shapes = [x.shape for x in flat]
-    ks = eq1_ks(config["thgs"], sizes)
-    sec = config["secagg"]
-    lr, server_lr = traffic["local_lr"], config["server_lr"]
+    ks = eq1_ks(config["thgs"], [int(x.size) for x in flat])
     params = [np.asarray(x).reshape(-1) for x in flat]
     params0 = {n: p.astype(np.float32).reshape(s)
                for n, p, s in zip(names, params, shapes)}
-    res = {c: [np.zeros(s, ndt) for s in sizes]
+    res = {c: [np.zeros(p.size, ndt) for p in params]
            for c in range(traffic["n_clients"])}
-    losses, params1 = [], None
+    losses, records = [], []
     for r, rd in enumerate(rounds):
         cohort = sorted(int(c) for c in rd["cohort"])
-        dropped = {int(c) for c in rd["dropped"]}
-        survivors = [c for c in cohort if c not in dropped]
-        C = len(cohort)
         p_tree = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(p.reshape(s)) for p, s in zip(params,
                                                                   shapes)])
-        deltas, round_losses = {}, []
-        for c in cohort:
-            xs, ys = rd["batches"][c]
-            d, loss = _local_sgd(p_tree, jnp.asarray(xs, jdt),
-                                 jnp.asarray(ys), forward=model.forward,
-                                 lr=lr, half=fault == "half_batch")
-            deltas[c] = [np.asarray(x).reshape(-1)
-                         for x in jax.tree_util.tree_leaves(d)]
-            round_losses.append(float(loss))
+        deltas, round_losses = _clients_sgd(
+            model, p_tree, cohort, rd["batches"], jdt, traffic["local_lr"],
+            fault == "half_batch")
         losses.append(float(np.mean(round_losses)))
-        seeds = {(a, b): pair_seed(sa_seed, a, b, r)
-                 for i, a in enumerate(cohort) for b in cohort[i + 1:]}
-        lost = survivors[0] if fault == "lost_upload" else None
-        for leaf_id, size in enumerate(sizes):
-            km = k_mask(sec, size, C)
-            k = min(ks[leaf_id], size)
-            agg = np.zeros(size, acc_dt)
-            for c in cohort:
-                acc = (res[c][leaf_id] + deltas[c][leaf_id]).astype(ndt)
-                if c in dropped:
-                    res[c][leaf_id] = acc
-                    continue
-                mag = np.abs(acc.astype(np.float32))
-                support = [np.argpartition(-mag, k - 1)[:k]]
-                for peer in cohort:
-                    if peer == c or not km:
-                        continue
-                    idx, u = pair_mask(seeds[min(c, peer), max(c, peer)],
-                                       leaf_id, km, size, sec["p"], sec["q"])
-                    support.append(idx)
-                    if fault == "no_recovery" and peer in dropped:
-                        np.add.at(agg, idx, (u if c < peer else -u)
-                                  .astype(acc_dt))
-                sup = np.unique(np.concatenate(support))
-                if c != lost:
-                    agg[sup] += acc[sup].astype(acc_dt)
-                acc[sup] = 0
-                res[c][leaf_id] = acc
-            update = (agg / len(survivors)).astype(ndt)
-            params[leaf_id] = (params[leaf_id]
-                               + ndt(server_lr) * update).astype(ndt)
-        if r == 0:
-            params1 = {n: p.astype(np.float32).reshape(s)
-                       for n, p, s in zip(names, params, shapes)}
+        _aggregate(params, res, deltas, cohort,
+                   {int(c) for c in rd["dropped"]}, ks, config,
+                   _pair_seeds(sa_seed, cohort, r), ndt, fault)
+        records.append(record(names, shapes, cohort, deltas, params, res))
     return Outputs(
-        losses=losses, params0=params0, params1=params1,
-        params_n={n: p.astype(np.float32).reshape(s)
-                  for n, p, s in zip(names, params, shapes)},
+        losses=losses, params0=params0,
         residuals={n: np.stack([res[c][i].astype(np.float32).reshape(s)
                                 for c in sorted(res)])
-                   for i, (n, s) in enumerate(zip(names, shapes))})
+                   for i, (n, s) in enumerate(zip(names, shapes))},
+        rounds=records)
+
+
+def forced(model, config: dict, traffic: dict, sa_seed: int, rounds: list,
+           other: Outputs) -> list:
+    """Teacher-forced mode: each compared round again in float32 from the
+    state that ``other`` (the program, or the control or a fault put in its
+    place) had at the round's start, on the same inputs. Local SGD runs
+    from ``other``'s params; the aggregation runs on ``other``'s own deltas
+    and residuals. So no difference carries over from an earlier round or
+    from local SGD into the aggregation, and each stage is read before
+    rounding has grown through later steps. One ``record`` per round."""
+    shapes = [x.shape for x in other.params0.values()]
+    treedef = jax.tree_util.tree_structure(
+        jax.eval_shape(model.init, jax.random.key(0)))
+    names = list(other.params0)
+    ks = eq1_ks(config["thgs"], [int(np.prod(s)) for s in shapes])
+
+    def flat(leaves: dict, i=None):
+        return [np.asarray(leaves[n] if i is None else leaves[n][i],
+                           np.float32).reshape(-1) for n in names]
+
+    params = flat(other.params0)
+    res = {c: [np.zeros(p.size, np.float32) for p in params]
+           for c in range(traffic["n_clients"])}
+    out = []
+    for r, (rd, taken) in enumerate(zip(rounds, other.rounds)):
+        cohort = sorted(int(c) for c in rd["cohort"])
+        p_tree = jax.tree_util.tree_unflatten(
+            treedef, [jnp.asarray(p.reshape(s)) for p, s in zip(params,
+                                                                  shapes)])
+        deltas, _ = _clients_sgd(
+            model, p_tree, cohort, rd["batches"], jnp.float32,
+            traffic["local_lr"], False)
+        after = list(params)
+        res_after = {c: list(res[c]) for c in cohort}
+        _aggregate(after, res_after,
+                   {c: flat(taken["deltas"], i) for i, c in enumerate(cohort)},
+                   cohort, {int(c) for c in rd["dropped"]}, ks, config,
+                   _pair_seeds(sa_seed, cohort, r), np.float32, None)
+        out.append(record(names, shapes, cohort, deltas, after, res_after))
+        params = flat(taken["params"])
+        for i, c in enumerate(cohort):
+            res[c] = flat(taken["residuals"], i)
+    return out

@@ -6,7 +6,8 @@
 The cell comes from ``BENCHMARK.json`` and the files it names
 (``spec.py``). A run builds the program's simulation from the seed, warms
 every program its rounds use (set-up), times back-to-back federated rounds
-for ``--seconds``, then compares its first rounds with the plain reference.
+for ``--seconds``, then compares its first rounds with the plain reference
+(``compare.py``: free-running and teacher-forced).
 ``--trace 1`` instead traces a window of at most
 ``harness.TRACE_WINDOW_S`` seconds and reports the per-layer metrics.
 

@@ -43,6 +43,38 @@ def jax_settings():
     jax.clear_caches()
 
 
+def tiny_cnn():
+    """The program-side model of ``data/configs/tiny_cnn.json``, built from
+    the program's own VGG layers: two 3x3 SAME convolutions of 8 channels,
+    each with bias, batch-statistics BatchNorm, ReLU and a 2x2 max pool,
+    then a 392-10 head, on 28x28x1."""
+    from repro.models import paper_models as pm
+
+    def init(key):
+        ks = jax.random.split(key, 3)
+        return {"c0": pm._conv(ks[0], 3, 3, 1, 8), "bn0": pm._bn(8),
+                "c1": pm._conv(ks[1], 3, 3, 8, 8), "bn1": pm._bn(8),
+                "head": pm._dense(ks[2], 392, 10)}
+
+    def apply(p, x):
+        h = x
+        for i in range(2):
+            h = pm._conv2d(h, p[f"c{i}"]["w"], p[f"c{i}"]["b"])
+            h = pm._pool(jax.nn.relu(pm._apply_bn(p[f"bn{i}"], h)))
+        return h.reshape(h.shape[0], -1) @ p["head"]["w"] + p["head"]["b"]
+
+    return pm.PaperModel("tiny_cnn", init, apply, (28, 28, 1))
+
+
+@pytest.fixture
+def tiny_cnn_model(monkeypatch):
+    """The tiny conv + BatchNorm model in the program's model table, for the
+    ``tiny_cnn_drop`` cell of ``data/``."""
+    from repro.models.paper_models import PAPER_MODELS
+
+    monkeypatch.setitem(PAPER_MODELS, "tiny_cnn", tiny_cnn())
+
+
 def tiny_argv(workload: str = "tiny_drop", seed: int = 2147483659,
               trace: int = 0, seconds: float = 1.0) -> list:
     return ["--workload", workload, "--seed", str(seed), "--seconds",

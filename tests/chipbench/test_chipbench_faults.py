@@ -1,13 +1,14 @@
 """``correct`` comes out false when the timed path is broken underneath
-(a run of the tiny CPU cell with one fault planted in the program), and
+(a run of a tiny CPU cell with one fault planted in the program), and
 for the control: the plain reference computed in bfloat16 put in the
-program's place."""
+program's place. ``tiny_drop`` (an MLP) compares the free-running numbers;
+``tiny_cnn_drop`` (conv + BatchNorm) only the teacher-forced ones."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from chipbench_testing import (DATA, any_device, jax_settings,  # noqa: F401
-                               load_script, tiny_argv)
+                               load_script, tiny_argv, tiny_cnn_model)
 
 from chipbench import compare, harness, spec
 
@@ -93,6 +94,37 @@ def test_control_is_not_correct(jax_settings):
     cell = spec.resolve("tiny_drop", DATA / "BENCHMARK.json", DATA)
     harness.configure_jax(cell, harness.spec.CHECKOUT)
     run = harness.run_program(cell, 2147483701, 0.0, 0.0)
+    sound = harness.check(cell, 2147483701, run)
+    control = harness.check(cell, 2147483701, run, dtype="bfloat16")
+    assert compare.verdict(sound, cell.limits)
+    assert not compare.verdict(control, cell.limits)
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_broken_conv_round_is_not_correct(fault, monkeypatch, tiny_cnn_model,
+                                          jax_settings):
+    jax.clear_caches()
+    FAULTS[fault](monkeypatch)
+    doc = run_script.main(tiny_argv("tiny_cnn_drop", seed=31),
+                          bench_file=DATA / "BENCHMARK.json", root=DATA,
+                          chip_check=any_device)
+    assert doc["correct"] is False
+    assert any(c["value"] == "nan" or c["value"] > c["limit"]
+               for c in doc["checks"].values())
+
+
+def test_conv_control_is_not_correct(tiny_cnn_model, jax_settings):
+    """The sound conv run is correct and the control is not; the harness
+    puts local SGD back once the compared rounds are recorded, and records
+    no other round."""
+    from repro.core import fedavg
+
+    update = fedavg.batched_client_update
+    cell = spec.resolve("tiny_cnn_drop", DATA / "BENCHMARK.json", DATA)
+    harness.configure_jax(cell, harness.spec.CHECKOUT)
+    run = harness.run_program(cell, 2147483701, 0.0, 0.0)
+    assert fedavg.batched_client_update is update
+    assert len(run.prog.rounds) == len(run.compare_rounds) >= 3
     sound = harness.check(cell, 2147483701, run)
     control = harness.check(cell, 2147483701, run, dtype="bfloat16")
     assert compare.verdict(sound, cell.limits)
