@@ -41,6 +41,8 @@ def main(argv=None) -> dict:
     harness.configure_jax(cell, CHECKOUT)
     seeds = [int(s) for s in args.seeds.split(",")]
     control = [int(s) for s in args.control_seeds.split(",") if s]
+    if not set(control) <= set(seeds):
+        ap.error("every --control-seeds seed must also be in --seeds")
     readings = {"program": {}, "control": {}, "faults": {}}
     kept = {}
     for seed in seeds:

@@ -31,6 +31,14 @@ residuals), so that they read each stage before rounding grows:
 * ``sgd_delta_median``: the cohort's local-SGD deltas, median leaf, worst
   round.
 
+The probe number reads local SGD's first step, which the harness takes in
+the compared rounds through the program's own compiled local SGD, run again
+on the round's params with ``lr`` 0 and each step's batch the first one
+(``harness.probe_sgd``):
+
+* ``sgd_step1``: each cohort client's step-1 loss (a forward pass at the
+  round-start params), ``|prog - ref| / |ref|``, worst client and round.
+
 Each round's negligible leaves follow the same rule on that round's
 reference update (for the deltas: on the reference's deltas).
 
@@ -39,14 +47,14 @@ Which numbers a kind of cell compares, and why (readings in PERF.md):
 * an MLP cell (``mlp_t2_secagg_drop``), whose local SGD keeps float32
   rounding small over a round, compares the free-running ``loss1`` and
   medians: the bfloat16 control fails ``loss1``, each fault a median;
-* VGG16 with BatchNorm grows float32 rounding to about 1e-2 within a
-  round's local steps, so no number read after local SGD tells the control
-  from a sound run. The teacher-forced ``agg_update_median`` does: a sound
-  aggregation agrees to rounding (about 1e-8), the control and a lost
-  upload read four to six orders of magnitude higher. ``sgd_delta_median``
-  catches a half batch, but not local SGD run in bfloat16 alone: that
-  needs the loss of a single local step, which the program does not
-  report. So a VGG16 cell is not in the benchmark yet.
+* VGG16 with BatchNorm (``vgg16_t2_secagg``) grows float32 rounding to
+  about 1e-2 within a round's local steps, so no number read after local
+  SGD tells the control from a sound run. The teacher-forced
+  ``agg_update_median`` and ``agg_update`` do for the aggregation: a sound
+  one agrees to rounding, the control and a lost upload read orders of
+  magnitude higher. ``sgd_delta_median`` catches a half batch. Local SGD
+  run in bfloat16 alone, or at the default matmul precision, is caught by
+  the probe's ``sgd_step1``, read before BatchNorm amplifies rounding.
 """
 from __future__ import annotations
 
@@ -57,7 +65,7 @@ import numpy as np
 NUMBERS = ("loss1", "loss", "update1", "change_n", "residual_n",
            "update1_median", "change_n_median", "residual_n_median",
            "agg_update", "agg_update_median", "agg_residual_median",
-           "sgd_delta_median")
+           "sgd_delta_median", "sgd_step1")
 NEGLIGIBLE = 1e-3
 
 
@@ -88,11 +96,15 @@ def _kept(ref_norms: dict) -> list:
     return [n for n, v in ref_norms.items() if v >= NEGLIGIBLE * median]
 
 
+def _loss_gaps(prog, ref) -> list:
+    return [float(abs(a - b) / abs(b)) for a, b in zip(prog, ref)]
+
+
 def _forced(prog, forced: list) -> dict:
     """The teacher-forced numbers (module docstring); ``forced`` holds
     ``reference.forced``'s record of each of ``prog.rounds``."""
     starts = [prog.params0] + [rd["params"] for rd in prog.rounds[:-1]]
-    upd, upd_med, res_med, delta_med = [], [], [], []
+    upd, upd_med, res_med, delta_med, step1 = [], [], [], [], []
     for start, mine, theirs in zip(starts, prog.rounds, forced):
         sides = (("prog", mine), ("ref", theirs))
         u = {side: {n: _norm(o["params"][n] - start[n]) for n in start}
@@ -108,9 +120,11 @@ def _forced(prog, forced: list) -> dict:
              for side, o in sides}
         delta_med.append(_median(_leaf_gaps(d["prog"], d["ref"],
                                             _kept(d["ref"]))))
+        step1.append(_worst(_loss_gaps(mine["step1"], theirs["step1"])))
     return {"agg_update": _worst(upd), "agg_update_median": _worst(upd_med),
             "agg_residual_median": _worst(res_med),
-            "sgd_delta_median": _worst(delta_med)}
+            "sgd_delta_median": _worst(delta_med),
+            "sgd_step1": _worst(step1)}
 
 
 def numbers(prog, ref, forced: list) -> dict:
@@ -126,8 +140,7 @@ def numbers(prog, ref, forced: list) -> dict:
            for side, o in (("prog", prog), ("ref", ref))}
     res = {side: {n: _norm(o.residuals[n]) for n in o.residuals}
            for side, o in (("prog", prog), ("ref", ref))}
-    loss_gaps = ([abs(a - b) / abs(b) for a, b in zip(prog.losses,
-                                                      ref.losses)]
+    loss_gaps = (_loss_gaps(prog.losses, ref.losses)
                  if len(prog.losses) == len(ref.losses) else [math.inf])
     out = {"loss1": loss_gaps[0], "loss": _worst(loss_gaps)}
     for name, per_side in (("update1", upd), ("change_n", chg),

@@ -14,7 +14,8 @@ decode with Bonawitz recovery, the server update). A hook that
    ``compare_through_first_drop`` on to the first round with a dropped
    client, so that recovery is compared. The deltas are taken from
    ``fedavg.batched_client_update``, wrapped while these rounds run and put
-   back before any other round does;
+   back before any other round does. The wrap also reads each client's
+   step-1 loss through the same compiled program (``probe_sgd``);
 2. rounds go on until every dropped count the mix can draw has run, so
    that every program the window uses is compiled, and (in a mix with
    ``dropout_blocks``) to the end of a block; all of that is set-up;
@@ -32,6 +33,7 @@ import collections
 import dataclasses
 import gc
 import glob
+import inspect
 import math
 import os
 import time
@@ -195,6 +197,29 @@ def flat_host(tree) -> dict:
                                                   for x in leaves)))
 
 
+def probe_sgd(update, args: tuple, kwargs: dict) -> dict:
+    """Each cohort client's step-1 loss (a forward pass at the round-start
+    params), read through the program's own compiled local SGD: ``update``
+    (``batched_client_update``) called again as in ``args``/``kwargs``, but
+    with ``lr`` 0 and every step's batch the first one, so that each step
+    runs at the round-start params and the mean loss is step 1's. ``lr`` is
+    traced, the step count and shapes are the round's and each argument is
+    passed as the round passed it (by position or by name), so the call is
+    the round's own program; the batches are laid out on the host, so
+    nothing compiles. Nothing of it feeds back into the round."""
+    import jax
+    import jax.numpy as jnp
+
+    names = list(inspect.signature(update).parameters)
+    call = dict(zip(names, args), **kwargs)
+    call["batches_stacked"] = jax.tree_util.tree_map(
+        lambda x: jnp.asarray(np.broadcast_to(np.asarray(x)[:, :1], x.shape)),
+        call["batches_stacked"])
+    call["lr"] = 0.0
+    head = [call.pop(n) for n in names[:len(args)]]
+    return {"step1": np.asarray(update(*head, **call)[1], np.float64)}
+
+
 def _record_facts(rec) -> dict:
     return {"ks": rec.ks, "k_masks": rec.k_masks, "n_clients": rec.n_clients,
             "n_survivors": rec.n_survivors, "model_size": rec.model_size,
@@ -267,7 +292,7 @@ class Driver:
         self.block = len(block) if block else 1
         self.seen, self.compare, self.losses = set(), [], []
         self.rounds = []
-        self._deltas = None
+        self._deltas = self._probe = None
         self.phase = "warm"
         self.round_ends, self.dropped, self.records = [], [], []
         self.spans = _Spans()
@@ -295,6 +320,9 @@ class Driver:
         update = fedavg.batched_client_update
 
         def capture_update(*a, **kw):
+            # the probe first, so that its outputs are freed before the
+            # round's own are made and the memory peak stays the round's
+            self._probe = probe_sgd(update, a, kw)
             out = update(*a, **kw)
             self._deltas = flat_host(out[0])
             return out
@@ -364,7 +392,7 @@ class Driver:
         self.rounds.append({
             "deltas": self._deltas, "params": flat_host(state.params),
             "residuals": {n: np.stack([rc[n] for rc in res])
-                          for n in res[0]}})
+                          for n in res[0]}, **self._probe})
         dropped_yet = any(rd["dropped"] for rd in self.compare)
         if r + 1 >= self.n_compare and (
                 dropped_yet or not self.through_drop or r + 1 >= WARM_CAP):

@@ -37,8 +37,15 @@ agrees to rounding, whatever local SGD's rounding grew to over its steps.
 control, which the comparison must refuse. ``fault`` plants one of the
 faults that the comparison must catch: ``half_batch`` (the loss of each
 local step over the first half of its batch), ``lost_upload`` (one
-survivor's values left out of the aggregate) and ``no_recovery`` (the
-survivors' masks toward dropped clients left in the aggregate).
+survivor's values left out of the aggregate), ``no_recovery`` (the
+survivors' masks toward dropped clients left in the aggregate) and
+``sgd_bf16`` (local SGD's forward and backward passes in bfloat16, params,
+update, aggregation and server update in float32: what the default matmul
+precision of a TPU does to the convolutions).
+
+Every round also records each client's step-1 loss (at the round-start
+params; the probe), read before a BatchNorm model's rounding grows over the
+later steps.
 """
 from __future__ import annotations
 
@@ -58,7 +65,7 @@ DH_GEN = 5
 IDX_SALT = 0x9E3779B9
 VAL_SALT = 0x85EBCA6B
 LEAF_SALT = 0xA511E9B3
-FAULTS = ("half_batch", "lost_upload", "no_recovery")
+FAULTS = ("half_batch", "lost_upload", "no_recovery", "sgd_bf16")
 NP_DTYPES = {"float32": np.float32, "bfloat16": ml_dtypes.bfloat16}
 
 
@@ -133,33 +140,45 @@ def _cross_entropy(forward, params, x, y):
     return -jnp.mean(jnp.take_along_axis(logp, y[:, None], 1))
 
 
-@partial(jax.jit, static_argnames=("forward", "lr", "half"))
-def _local_sgd(params, xs, ys, *, forward, lr, half):
+@partial(jax.jit, static_argnames=("forward", "lr", "half", "compute"))
+def _local_sgd(params, xs, ys, *, forward, lr, half, compute):
+    """Plain SGD over the steps of ``xs``/``ys``: (delta, mean loss, the
+    first step's loss). ``compute`` names a dtype that the loss casts params
+    and inputs to (``sgd_bf16``), or is None."""
+    def loss_fn(p, x, y):
+        if compute is not None:
+            p = jax.tree_util.tree_map(lambda a: a.astype(compute), p)
+            x = x.astype(compute)
+        return _cross_entropy(forward, p, x, y)
+
     def step(p, batch):
         x, y = batch
         if half:
             x, y = x[: x.shape[0] // 2], y[: y.shape[0] // 2]
-        loss, g = jax.value_and_grad(partial(_cross_entropy, forward))(
-            p, x, y)
+        loss, g = jax.value_and_grad(loss_fn)(p, x, y)
         return jax.tree_util.tree_map(lambda a, b: a - lr * b, p, g), loss
 
     new, losses = jax.lax.scan(step, params, (xs, ys))
+    losses = losses.astype(jnp.float32)
     delta = jax.tree_util.tree_map(jnp.subtract, new, params)
-    return delta, jnp.mean(losses.astype(jnp.float32))
+    return delta, jnp.mean(losses), losses[0]
 
 
-def _clients_sgd(model, p_tree, cohort, batches, jdt, lr, half):
+def _clients_sgd(model, p_tree, cohort, batches, jdt, lr, half,
+                 compute=None):
     """Every cohort client's local SGD from ``p_tree``: (flat deltas per
-    client, mean loss per client)."""
-    deltas, losses = {}, []
+    client, mean loss per client, step-1 loss per client)."""
+    deltas, losses, step1 = {}, [], []
     for c in cohort:
         xs, ys = batches[c]
-        d, loss = _local_sgd(p_tree, jnp.asarray(xs, jdt), jnp.asarray(ys),
-                             forward=model.forward, lr=lr, half=half)
+        d, loss, first = _local_sgd(
+            p_tree, jnp.asarray(xs, jdt), jnp.asarray(ys),
+            forward=model.forward, lr=lr, half=half, compute=compute)
         deltas[c] = [np.asarray(x).reshape(-1)
                      for x in jax.tree_util.tree_leaves(d)]
         losses.append(float(loss))
-    return deltas, losses
+        step1.append(float(first))
+    return deltas, losses, step1
 
 
 # ------------------------------------------------------------ aggregation
@@ -237,10 +256,11 @@ def leaf_names(tree) -> list[str]:
             for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
 
 
-def record(names, shapes, cohort, deltas, params, res) -> dict:
+def record(names, shapes, cohort, deltas, params, res, step1) -> dict:
     """One compared round as the comparison reads it, in float32: the
     (sorted) cohort's local-SGD ``deltas`` and residuals after the round
-    (name -> [C, ...]), and the params after it (name -> array)."""
+    (name -> [C, ...]), the params after it (name -> array), and each
+    client's step-1 loss (``step1``, [C])."""
     def stacked(per_client):
         return {n: np.stack([per_client[c][i].astype(np.float32).reshape(s)
                              for c in cohort])
@@ -249,7 +269,8 @@ def record(names, shapes, cohort, deltas, params, res) -> dict:
     return {"deltas": stacked(deltas),
             "params": {n: p.astype(np.float32).reshape(s)
                        for n, p, s in zip(names, params, shapes)},
-            "residuals": stacked(res)}
+            "residuals": stacked(res),
+            "step1": np.asarray(step1, np.float64)}
 
 
 def run(model, config: dict, traffic: dict, seed: int, sa_seed: int,
@@ -278,14 +299,16 @@ def run(model, config: dict, traffic: dict, seed: int, sa_seed: int,
         p_tree = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(p.reshape(s)) for p, s in zip(params,
                                                                   shapes)])
-        deltas, round_losses = _clients_sgd(
+        deltas, round_losses, step1 = _clients_sgd(
             model, p_tree, cohort, rd["batches"], jdt, traffic["local_lr"],
-            fault == "half_batch")
+            fault == "half_batch",
+            "bfloat16" if fault == "sgd_bf16" else None)
         losses.append(float(np.mean(round_losses)))
         _aggregate(params, res, deltas, cohort,
                    {int(c) for c in rd["dropped"]}, ks, config,
                    _pair_seeds(sa_seed, cohort, r), ndt, fault)
-        records.append(record(names, shapes, cohort, deltas, params, res))
+        records.append(record(names, shapes, cohort, deltas, params, res,
+                              step1))
     return Outputs(
         losses=losses, params0=params0,
         residuals={n: np.stack([res[c][i].astype(np.float32).reshape(s)
@@ -302,7 +325,8 @@ def forced(model, config: dict, traffic: dict, sa_seed: int, rounds: list,
     from ``other``'s params; the aggregation runs on ``other``'s own deltas
     and residuals. So no difference carries over from an earlier round or
     from local SGD into the aggregation, and each stage is read before
-    rounding has grown through later steps. One ``record`` per round."""
+    rounding has grown through later steps; the step-1 loss is read at
+    ``other``'s params too. One ``record`` per round."""
     shapes = [x.shape for x in other.params0.values()]
     treedef = jax.tree_util.tree_structure(
         jax.eval_shape(model.init, jax.random.key(0)))
@@ -322,7 +346,7 @@ def forced(model, config: dict, traffic: dict, sa_seed: int, rounds: list,
         p_tree = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(p.reshape(s)) for p, s in zip(params,
                                                                   shapes)])
-        deltas, _ = _clients_sgd(
+        deltas, _, step1 = _clients_sgd(
             model, p_tree, cohort, rd["batches"], jnp.float32,
             traffic["local_lr"], False)
         after = list(params)
@@ -331,7 +355,8 @@ def forced(model, config: dict, traffic: dict, sa_seed: int, rounds: list,
                    {c: flat(taken["deltas"], i) for i, c in enumerate(cohort)},
                    cohort, {int(c) for c in rd["dropped"]}, ks, config,
                    _pair_seeds(sa_seed, cohort, r), np.float32, None)
-        out.append(record(names, shapes, cohort, deltas, after, res_after))
+        out.append(record(names, shapes, cohort, deltas, after, res_after,
+                          step1))
         params = flat(taken["params"])
         for i, c in enumerate(cohort):
             res[c] = flat(taken["residuals"], i)

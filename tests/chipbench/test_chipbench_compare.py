@@ -12,7 +12,8 @@ from chipbench.reference import Outputs
 
 
 def _outputs(scale=1.0, bias_update=1e-9, loss=(2.0, 1.0, 0.5)):
-    """One compared round; its record's deltas are the update."""
+    """One compared round; its record's deltas are the update, each
+    client's step-1 loss the first of ``loss``."""
     rng = np.random.default_rng(0)
     p0 = {"a": rng.normal(size=100), "b": rng.normal(size=10),
           "c": np.zeros(5)}
@@ -21,7 +22,7 @@ def _outputs(scale=1.0, bias_update=1e-9, loss=(2.0, 1.0, 0.5)):
     p1 = {k: p0[k] + scale * upd[k] for k in p0}
     res = {k: np.stack([upd[k], -upd[k]]) for k in p0}
     rounds = [{"deltas": {k: np.stack([upd[k], upd[k]]) for k in p0},
-               "params": p1, "residuals": res}]
+               "params": p1, "residuals": res, "step1": np.full(2, loss[0])}]
     return Outputs(losses=list(loss), params0=p0, residuals=res,
                    rounds=rounds)
 
@@ -76,6 +77,23 @@ def test_forced_gaps_and_nan():
     assert math.isnan(nums["agg_update"])
     assert math.isnan(nums["agg_update_median"])
     assert not compare.verdict(nums, {"agg_update_median": 1.0})
+
+
+def test_probe_gaps_and_nan():
+    """``sgd_step1`` reads each client's step-1 loss, worst client; it
+    reads nothing of the later steps, and a NaN fails."""
+    ref = _outputs(loss=(2.0, 1.0, 0.5))
+    prog = _outputs(loss=(2.0, 1.1, 0.5))
+    prog.rounds[0]["step1"][1] = 2.2
+    nums = _numbers(prog, ref)
+    assert nums["sgd_step1"] == pytest.approx(0.1)
+    assert nums["sgd_delta_median"] == 0.0
+    assert not compare.verdict(nums, {"sgd_step1": 0.05})
+    assert compare.verdict(nums, {"sgd_step1": 0.2})
+    prog.rounds[0]["step1"][0] = np.nan
+    nums = _numbers(prog, ref)
+    assert math.isnan(nums["sgd_step1"])
+    assert not compare.verdict(nums, {"sgd_step1": 1.0})
 
 
 def test_loss_gaps_and_nan():
