@@ -12,7 +12,9 @@ from the trained params through ``serving.InferenceServer``. Phase C checks
 the Pallas kernels on the chip at VGG16's largest leaf (2,359,296 params)
 for a 5-client cohort: the pair masks alone cancel to exactly 0 through the
 decode kernel, the decode equals XLA's scatter-add exactly on grid-valued
-streams, and the mask and pack kernels equal their jnp twins bit for bit.
+streams, the mask and pack kernels equal their jnp twins bit for bit, and
+the encode's threshold select (``ops.topk_select``) gives ``lax.top_k``'s
+index set at 2,359,296 and 4,718,592 entries with ties at the threshold.
 
 ``--four-chips`` runs the same VGG16 config at cohort 8 of 16 clients with
 the cohort sharded over every local chip (``shard_clients='on'``) against
@@ -227,6 +229,25 @@ def phase_kernels(size: int = LEAF, cohort: int = COHORT, seed: int = 0):
             f"bitpack_rows == jnp twin at width {width}")
         check(np.array_equal(ops.bitunpack_rows(words, k=k, width=width), u),
               f"bitunpack_rows round trip at width {width}")
+
+    # the encode's threshold select against lax.top_k, at VGG16's largest
+    # leaf and the CIFAR-MLP's, 5 rows each with copies of the k-th
+    # magnitude planted on both sides of the cut
+    for m, kk_ in ((size, k), (4_718_592, 264_529)):
+        rng = np.random.default_rng([seed, m])
+        acc = rng.standard_normal((cohort, m)).astype(np.float32)
+        for row in acc:
+            kth = np.sort(np.abs(row))[m - kk_]
+            below = np.flatnonzero(np.abs(row) < kth)
+            row[rng.choice(below, 64, replace=False)] = kth * rng.choice(
+                [-1.0, 1.0], 64)
+        acc = jnp.asarray(acc)
+        got = np.asarray(ops.topk_select(acc, k=kk_))
+        want = np.sort(np.asarray(jax.jit(
+            lambda a: jax.lax.top_k(jnp.abs(a), kk_)[1])(acc)), axis=1)
+        check(np.array_equal(got, want),
+              f"topk_select == lax.top_k's index set ({cohort} x {m:,}, "
+              f"k {kk_:,}, ties at the threshold)")
 
 
 # ------------------------------------------------------- --four-chips phase

@@ -396,10 +396,13 @@ def run_round(
         for leaf_id, (d_st, r_st, k, k_mask, size, shape) in enumerate(
                 zip(delta_leaves, res_stacked, ks, k_masks, leaf_sizes,
                     leaf_shapes)):
+            select_rows = se.select_rows(C, k, 1, size, thgs.selector,
+                                         dp=dp_sigma_c > 0.0)
             if not sharded:
                 # ---- 2. batched unified-stream encode (all clients, one
                 # jit) ----
-                with TraceAnnotation("fl.encode", leaf=leaf_id):
+                with TraceAnnotation("fl.encode", leaf=leaf_id,
+                                     select_rows=select_rows):
                     streams_b, new_res = se.encode_leaf_batch(
                         d_st, r_st, k=k, nb=1, m=size, size=size,
                         selector=thgs.selector, sample_frac=thgs.sample_frac,
@@ -410,12 +413,13 @@ def run_round(
                         dp_support_seed=dp_sup_seed)
             splits = (se.tree_splits(size, groups) if topology == "tree"
                       else ())
+            stats = dict(leaf=leaf_id, scatter_steps=se.scatter_steps(
+                C, k, k_mask, 1, size, recovery=bool(dropped),
+                splits=splits))
+            if sharded:     # the encode runs inside this span
+                stats["select_rows"] = select_rows
             with TraceAnnotation(
-                    "fl.encode_decode" if sharded else "fl.decode",
-                    leaf=leaf_id,
-                    scatter_steps=se.scatter_steps(
-                        C, k, k_mask, 1, size, recovery=bool(dropped),
-                        splits=splits)):
+                    "fl.encode_decode" if sharded else "fl.decode", **stats):
                 if sharded:
                     # ---- 2+3. client-parallel encode + fused decode: one
                     # shard_map program per leaf (DESIGN.md §11) ----
@@ -652,7 +656,9 @@ def run_async_update(
     for leaf_id, (d_st, r_st, k, size, shape) in enumerate(
             zip(delta_leaves, res_stacked, ks, leaf_sizes, leaf_shapes)):
         # ---- 2. batched unified-stream encode, staleness-weighted ----
-        with TraceAnnotation("fl.encode", leaf=leaf_id):
+        with TraceAnnotation("fl.encode", leaf=leaf_id,
+                             select_rows=se.select_rows(B, k, 1, size,
+                                                        thgs.selector)):
             streams_b, new_res = se.encode_leaf_batch(
                 d_st, r_st, k=k, nb=1, m=size, size=size,
                 selector=thgs.selector, sample_frac=thgs.sample_frac,

@@ -114,9 +114,49 @@ def first_occurrence_rows(idx: jax.Array) -> jax.Array:
 
 
 # ------------------------------------------------------------- selector stage
+# The shortest row for which the exact selectors take the threshold select
+# (kernels/topk_select.py) in place of ``lax.top_k``, which sorts the row on
+# a TPU. Device time on a v5e, 5 rows, ms for top_k / select: m 1,020
+# 0.011 / 0.010; 1,536 0.001 / 0.014; 5,120 0.037 / 0.015; 36,864
+# 0.44 / 0.029; 1,059,840 19.8 / 0.67; 4,718,592 111.2 / 5.1. The select
+# wins from 5,120 entries on, and loses or ties up to 1,536.
+SELECT_MIN_M = 4_096
+
+
+def uses_select(m: int, k: int, selector: str = "exact") -> bool:
+    """Whether :func:`select_topk_rows` takes the threshold select for rows
+    of ``m`` entries and top ``k`` (static shapes only)."""
+    from repro.kernels.topk_select import fits_vmem
+
+    return (selector != "sampled" and m >= SELECT_MIN_M and k >= 1
+            and fits_vmem(k))
+
+
+def select_rows(n_clients: int, k: int, nb: int, m: int, selector: str,
+                dp: bool = False) -> int:
+    """Rows of one leaf's encode that take the threshold select: the
+    ``select_rows`` stat of the ``fl.encode`` span. 0 where the leaf keeps
+    ``lax.top_k`` or the DP release bypasses the top-k."""
+    if dp or not uses_select(m, min(int(k), m), selector):
+        return 0
+    return n_clients * nb
+
+
 def select_topk_rows(acc: jax.Array, k: int, selector: str,
                      sample_frac: float) -> jax.Array:
-    """[n_blocks, m] -> int32[n_blocks, k] per-row top-|.| indices."""
+    """[n_blocks, m] -> int32[n_blocks, k] per-row top-|.| indices.
+
+    The exact selectors give the same index set either way; the threshold
+    select gives it in ascending order, ``lax.top_k`` by falling magnitude.
+    Within a client an index is in at most one top-k slot and the top-k
+    slots precede the mask slots, so the first-occurrence gate and the
+    decode's stable sort by index see every index's slots in the same order
+    either way: streams, residuals and the decoded sum are bit for bit the
+    same (DESIGN.md §3)."""
+    if uses_select(acc.shape[-1], k, selector):
+        from repro.kernels import ops
+
+        return ops.topk_select(acc, k=k)
     abs_acc = jnp.abs(acc)
     if selector == "sampled":
         from repro.core.sparsify import _sampled_topk

@@ -3,7 +3,8 @@
 Off-TPU the kernels execute in interpret mode — the kernel body runs as
 traced jnp ops, which is how correctness is validated against ref.py. On TPU
 they compile to Mosaic. The main-path kernels (``stream_scatter_add``,
-``pair_mask_streams``, ``bitpack_rows``/``bitunpack_rows``) are compiled for
+``pair_mask_streams``, ``bitpack_rows``/``bitunpack_rows``,
+``topk_select``) are compiled for
 a described v5e in tests/test_tpu_compile.py and checked on the chip by
 chip_smoke.py. ``flash_attention``, ``thgs_sparsify`` and
 ``mask_prng_apply`` have no caller on the main path and have not been
@@ -25,6 +26,7 @@ from repro.kernels.pack import bitpack_rows as _bitpack
 from repro.kernels.pack import bitunpack_rows as _bitunpack
 from repro.kernels.stream_decode import stream_scatter_add as _scatter
 from repro.kernels.thgs_sparsify import thgs_sparsify as _thgs
+from repro.kernels import topk_select as _topk
 
 
 def _interpret() -> bool:
@@ -94,3 +96,33 @@ def bitunpack_rows(words, *, k: int, width: int):
     if _interpret():
         return ref.bitunpack_rows_ref(words, k, width)
     return _bitunpack(words, k, width)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def topk_select(acc, *, k: int):
+    """Exact top-k of ``|acc|`` along the last axis: int32[..., k] columns
+    in ascending order, ties at the k-th magnitude taken lowest column first
+    (``lax.top_k``'s set, in another order). The threshold bisection, then
+    the Pallas compaction kernel on TPU; the jnp twin elsewhere (the ref IS
+    the fallback — interpret-mode kernel parity is pinned in
+    tests/test_kernels.py). Under ``vmap`` the mapped axis joins the rows of
+    one kernel call."""
+    m = acc.shape[-1]
+
+    @jax.custom_batching.custom_vmap
+    def rows(a):
+        a2 = a.reshape(-1, m)
+        a3 = _topk.row_view(a2)
+        thr, need = _topk.row_thresholds(a3, k)
+        if _interpret():
+            out = ref.topk_compact_ref(a2, thr, need, k)
+        else:
+            out = _topk.topk_compact(a3, thr, need, k, m)
+        return out.reshape(a.shape[:-1] + (k,))
+
+    @rows.def_vmap
+    def _rows_vmap(axis_size, in_batched, a):
+        del axis_size, in_batched   # the mapped axis leads: one more row axis
+        return rows(a), True
+
+    return rows(acc)

@@ -271,3 +271,23 @@ def mask_prng_ref(g, seed: int, *, p: float, q: float, sigma: float,
     u = p + q * (h.astype(jnp.float32) / jnp.float32(2**32))
     mask = jnp.where(u < sigma, u, 0.0).reshape(g.shape) * sign
     return (g.astype(jnp.float32) + mask).astype(g.dtype), mask
+
+
+def topk_compact_ref(acc, thr, need, k: int) -> jax.Array:
+    """Per row of ``acc`` [R, m]: the ascending columns of the entries whose
+    ``|acc|`` bits (``topk_select.keys_of``) exceed ``thr``, then of the
+    first ``need`` equal to it — ``kernels/topk_select.topk_compact``'s
+    output. int32[R, k]."""
+    from repro.kernels.topk_select import keys_of
+
+    key = keys_of(acc)
+    t = thr.astype(jnp.int32)[:, None]
+    eq = key == t
+    sel = (key > t) | (eq & (jnp.cumsum(eq, axis=1, dtype=jnp.int32)
+                             <= need.astype(jnp.int32)[:, None]))
+    slot = jnp.where(sel, jnp.cumsum(sel, axis=1, dtype=jnp.int32) - 1, k)
+    rows = jnp.arange(acc.shape[0])[:, None]
+    cols = jnp.broadcast_to(jnp.arange(acc.shape[1], dtype=jnp.int32),
+                            acc.shape)
+    out = jnp.zeros((acc.shape[0], k + 1), jnp.int32).at[rows, slot].set(cols)
+    return out[:, :k]

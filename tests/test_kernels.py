@@ -273,3 +273,28 @@ def test_packed_words():
     assert ref.packed_words(33, 1) == 2
     assert ref.packed_words(1, 32) == 1
     assert ref.packed_words(100, 17) == -(-100 * 17 // 32)
+
+
+@pytest.mark.parametrize("rows,m,k,block_rows,pattern", [
+    pytest.param(3, 5000, 300, 8, "normal", id="blocks_of_8_rows"),
+    pytest.param(2, 4000, 2000, 16, "ties", id="ties_straddle_blocks"),
+    pytest.param(2, 1000, 1000, 8, "normal", id="k_eq_m"),
+    pytest.param(2, 70_380, 2151, 512, "normal", id="mlp_l2w_two_blocks"),
+    pytest.param(1, 66_000, 900, 512, "ties", id="block_overhangs_row"),
+])
+def test_topk_select_kernel_matches_ref(rows, m, k, block_rows, pattern):
+    """The compaction kernel in interpret mode against its jnp twin, at the
+    thresholds the bisection finds."""
+    from repro.kernels import topk_select
+
+    key = jax.random.fold_in(KEY, 30)
+    if pattern == "ties":
+        acc = jax.random.randint(key, (rows, m), -5, 6).astype(jnp.float32)
+    else:
+        acc = jax.random.normal(key, (rows, m))
+    acc3 = topk_select.row_view(acc)
+    thr, need = topk_select.row_thresholds(acc3, k)
+    out = topk_select.topk_compact(acc3, thr, need, k, m,
+                                   block_rows=block_rows, interpret=True)
+    exp = ref.topk_compact_ref(acc, thr, need, k)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))

@@ -139,6 +139,22 @@ def test_decode_spans_count_scatter_steps(sync_sim):
                 if s[0] == "fl.decode"] == want
 
 
+def test_encode_spans_count_select_rows(sync_sim):
+    """Each ``fl.encode`` span carries the rows its leaf's top-k takes
+    through the threshold select: the cohort where the leaf reaches the
+    crossover, 0 where it keeps ``lax.top_k``."""
+    from repro.core import streams
+
+    sim, spans = sync_sim
+    for (_, inside), rec in zip(by_round(spans), sim.state.comm_log):
+        want = [streams.select_rows(rec.n_clients, k, 1, size,
+                                    _SYNC.thgs.selector)
+                for k, size in zip(rec.ks, rec.leaf_sizes)]
+        assert [s[3]["select_rows"] for s in inside
+                if s[0] == "fl.encode"] == want
+        assert max(want) == rec.n_clients and min(want) == 0
+
+
 def test_stats_are_ints(sync_run):
     spans, _ = sync_run
     values = [v for s in spans for v in s[3].values()]
@@ -163,6 +179,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path[:0] = ["src", "tests"]
 import jax
+from repro.core import streams
 from repro.sim import Simulation
 from test_round_spans import by_round, program_spans, _SYNC
 import dataclasses
@@ -177,6 +194,12 @@ print(json.dumps({"names": sorted({s[0] for s in inside}),
                              if s[0] == "fl.encode_decode"],
                   "steps": [s[3]["scatter_steps"] for s in inside
                             if s[0] == "fl.encode_decode"],
+                  "select": [s[3]["select_rows"] for s in inside
+                             if s[0] == "fl.encode_decode"],
+                  "want": [streams.select_rows(
+                      rec.n_clients, k, 1, size, cfg.thgs.selector)
+                      for rec in sim.state.comm_log
+                      for k, size in zip(rec.ks, rec.leaf_sizes)],
                   "n_leaves": len(jax.tree_util.tree_leaves(
                       sim.state.params))}))
 """
@@ -197,3 +220,4 @@ def test_sharded_round_spans_encode_decode_per_leaf(tmp_path):
         "fl.encode_decode"}) - {"fl.encode", "fl.decode"}
     assert doc["leaves"] == list(range(doc["n_leaves"]))
     assert len(doc["steps"]) == doc["n_leaves"] and min(doc["steps"]) >= 2
+    assert doc["select"] == doc["want"] and max(doc["select"]) > 0

@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import codecs
 from repro.core.types import SecureAggConfig
-from repro.kernels import mask_prng, pack, stream_decode
+from repro.kernels import mask_prng, pack, stream_decode, topk_select
 
 LEAF = 2_359_296                      # VGG16's 3x3x512x512 conv kernel
 COHORT = 5
@@ -104,6 +104,23 @@ def test_bitunpack_rows_compiles(one_chip, width):
     words = -(-K * width // 32)
     _compile_for_chip(lambda w: pack.bitunpack_rows(w, K, width), one_chip,
                       ((COHORT, words), jnp.uint32))
+
+
+@pytest.mark.parametrize("m,k", [
+    pytest.param(4_718_592, 264_529, id="cifar_mlp_l0w"),   # blocks fit
+    pytest.param(1_059_840, 33_032, id="cifar_mlp_l1w"),    # last overhangs
+])
+def test_topk_select_compiles(one_chip, m, k):
+    """The encode's select at the benchmark's two largest leaves, 5 rows
+    (one per client): the bisection and the compaction kernel."""
+    def select(acc):
+        acc3 = topk_select.row_view(acc)
+        thr, need = topk_select.row_thresholds(acc3, k)
+        return topk_select.topk_compact(acc3, thr, need, k, m)
+
+    text = _compile_for_chip(select, one_chip, ((COHORT, m), jnp.float32))
+    assert re.search(r"%topk_select(\.\d+)? = ", text)
+    assert not re.search(r" sort\(", text)
 
 
 def test_decode_product_runs_at_highest_precision():
